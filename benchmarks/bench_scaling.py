@@ -7,23 +7,21 @@ headline is that cost grows sub-quadratically with size.
 
 ``dp/`` rows: data-parallel device-pipeline step time at 1/2/4/8 fake
 CPU devices with the *global* batch held fixed (the shard_map path of
-docs/pipeline.md §Data-parallel).  Each measurement runs in a
-subprocess because the fake-device flag must be set before jax imports
-(see ``benchmarks/dp_child.py``).  On real multi-chip hardware the
+docs/pipeline.md §Data-parallel).  On the CPU each measurement runs in
+a subprocess because the fake-device flag must be set before jax
+imports; on real devices it runs in this process, which holds the
+chips (see ``benchmarks/dp_child.py``).  On real multi-chip hardware the
 speedup column is the near-linear scaling claim; on a CI box it
 saturates at the physical core count — the acceptance bar is that every
 sharded row is no slower than the 1-device baseline.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
 
+from benchmarks import dp_child
 from benchmarks.common import Bench
 from repro.core.dist_graph import PartitionedGraph
 from repro.data import make_scaling_graph
@@ -35,21 +33,7 @@ from repro.trainer import (GSgnnAccEvaluator, GSgnnData, GSgnnNodeDataLoader,
 
 
 def _dp_child(dp: int, epochs: int, flags=(), **kw) -> dict:
-    cmd = [sys.executable, "-m", "benchmarks.dp_child",
-           "--dp", str(dp), "--epochs", str(epochs)]
-    cmd += [f"--{f.replace('_', '-')}" for f in flags]
-    for k, v in kw.items():
-        cmd += [f"--{k.replace('_', '-')}", str(v)]
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src"),
-         env.get("PYTHONPATH", "")])
-    out = subprocess.run(cmd, capture_output=True, text=True,
-                         timeout=1200, env=env)
-    lines = [ln for ln in out.stdout.splitlines()
-             if ln.startswith("DPRESULT:")]
-    assert lines, (out.returncode, out.stderr[-2000:])
-    return json.loads(lines[0][len("DPRESULT:"):])
+    return dp_child.run(flags, dp=dp, epochs=epochs, **kw)
 
 
 def _bench_data_parallel(bench: Bench, fast: bool = True):

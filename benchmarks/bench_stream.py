@@ -16,7 +16,7 @@ of the engine's overlap machinery is on:
   host re-sample + per-batch loop every epoch) + ``async_checkpoint``
   (fetch + atomic write on the background writer thread).
 
-Each subprocess warms up with ``runner.train()`` (compiles every
+Each measurement warms up with ``runner.train()`` (compiles every
 program), then times ``--timed-epochs`` full epochs end to end —
 staging + train + eval + checkpoint (``benchmarks/dp_child.py``).  The
 derived ``overlap_efficiency`` column on ``stream/overlap`` is
@@ -25,30 +25,11 @@ wall-clock <= 0.9x blocking (efficiency >= 1.11) at equal work.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 import tempfile
 
 from benchmarks.common import Bench
-
-
-def _child(flags=(), **kw) -> dict:
-    cmd = [sys.executable, "-m", "benchmarks.dp_child"]
-    cmd += [f"--{f.replace('_', '-')}" for f in flags]
-    for k, v in kw.items():
-        cmd += [f"--{k.replace('_', '-')}", str(v)]
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src"),
-         env.get("PYTHONPATH", "")])
-    out = subprocess.run(cmd, capture_output=True, text=True,
-                         timeout=1200, env=env)
-    lines = [ln for ln in out.stdout.splitlines()
-             if ln.startswith("DPRESULT:")]
-    assert lines, (out.returncode, out.stderr[-2000:])
-    return json.loads(lines[0][len("DPRESULT:"):])
+from benchmarks.dp_child import run as _child
 
 
 def _stream_rows(bench: Bench, n_nodes: int, batch: int, warm: int,

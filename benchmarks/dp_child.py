@@ -1,11 +1,15 @@
-"""Subprocess worker for the data-parallel / LP rows of ``bench_scaling``.
+"""One training measurement for the data-parallel / LP / stream rows of
+``bench_scaling`` and ``bench_stream``.
 
-Runs one training measurement in a fresh process because
-``--xla_force_host_platform_device_count`` must be set before the first
-jax import (the parent bench process is already single-device).  Prints
-one ``DPRESULT:{json}`` line: median steady-state seconds per step
-(epoch 0 compiles and is discarded) and the final loss, so the parent
-can assert loss parity across shard counts as well as timing.
+``run(flags, **kw)`` takes the measurement where the devices are.  On
+real accelerators it runs in the calling process: a chip belongs to one
+process, and the bench parent already holds it.  On the CPU it starts
+``python -m benchmarks.dp_child`` in a fresh process, because
+``--xla_force_host_platform_device_count`` (the fake devices the dp rows
+need) must be set before the first jax import.  The child prints one
+``DPRESULT:{json}`` line: median steady-state seconds per step (epoch 0
+compiles and is discarded) and the final loss, so the parent can assert
+loss parity across shard counts as well as timing.
 
 ``--task link_prediction`` measures the LP device step (negatives drawn
 in-jit, in-batch ``B x B`` scoring per shard against the all-gathered
@@ -19,10 +23,11 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
 import sys
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--dp", type=int, required=True)
     ap.add_argument("--devices", type=int, default=8)
@@ -64,13 +69,11 @@ def main():
                          "program), time this many additional epochs "
                          "end to end — train + eval + checkpoint wall "
                          "clock per epoch goes out as epoch_wall_us")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={args.devices}")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+def measure(args) -> dict:
+    """Train one config and return its DPRESULT record."""
     import numpy as np
 
     from repro.config import GSConfig
@@ -149,7 +152,38 @@ def main():
     metric = runner.trainer.evaluator.name
     if metric in hist[-1]:
         out[metric] = hist[-1][metric]
-    print("DPRESULT:" + json.dumps(out))
+    return out
+
+
+def run(flags=(), **kw) -> dict:
+    """Measure one config (``flags``: store-true options, ``kw``: valued
+    options, both by their argument names)."""
+    argv = [f"--{f.replace('_', '-')}" for f in flags]
+    for k, v in kw.items():
+        argv += [f"--{k.replace('_', '-')}", str(v)]
+    import jax
+    if jax.default_backend() != "cpu":
+        return measure(parse_args(argv))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    out = subprocess.run([sys.executable, "-m", "benchmarks.dp_child"]
+                         + argv, capture_output=True, text=True,
+                         timeout=1200, env=env)
+    lines = [ln for ln in out.stdout.splitlines()
+             if ln.startswith("DPRESULT:")]
+    assert lines, (out.returncode, out.stderr[-2000:])
+    return json.loads(lines[0][len("DPRESULT:"):])
+
+
+def main():
+    args = parse_args()
+    # CPU only: fake devices for the dp rows (set before jax is imported)
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={args.devices}")
+    print("DPRESULT:" + json.dumps(measure(args)))
 
 
 if __name__ == "__main__":

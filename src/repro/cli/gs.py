@@ -36,11 +36,13 @@ import json
 import sys
 
 from repro.checkpoint import load_run_config
+from repro.common.compile_cache import enable_compile_cache
 from repro.config import GSConfig, apply_overrides, load_config_dict
 from repro.runner import TASK_REGISTRY, run_config
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.cli.gs",
         description="single-command GraphStorm runner; any config key can "

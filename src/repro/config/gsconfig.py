@@ -176,11 +176,9 @@ class GnnConfig:
     # previously hardcoded to 16 in each CLI
     sparse_embed_dim: int = _field("int", 16)
     # Pallas kernel routing (replaces the old set_use_pallas global):
-    # route aggregation/sampling hot loops through the Pallas kernels;
-    # pallas_interpret=true keeps the CPU interpreter (kernel debugging),
-    # set it false on real TPU for compiled kernels
+    # route aggregation/sampling hot loops through the Pallas kernels,
+    # compiled on a TPU backend and interpreted on the CPU
     use_pallas: bool = _field("bool", False)
-    pallas_interpret: bool = _field("bool", True)
 
 
 @dataclasses.dataclass

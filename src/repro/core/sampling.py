@@ -208,7 +208,7 @@ class SamplePlan:
     Fully determined by (seed counts, fanouts, graph etypes) — the same
     invariant that makes ``BlockSchema`` a jit cache key — and laid out
     *identically* to the host sampler's MFG blocks, so the same
-    ``gather_seg_aggr`` kernels consume either path.  ``layers[0]``
+    aggregation (``seg_aggr``) consumes either path.  ``layers[0]``
     consumes raw features (host block order).
     """
     layers: Tuple[PlanLayer, ...]
@@ -278,7 +278,7 @@ class DeviceNeighborSampler:
     """
 
     def __init__(self, graph: HeteroGraph, fanouts: Sequence, seed: int = 0,
-                 use_pallas: bool = False, interpret: bool = True,
+                 use_pallas: bool = False,
                  mesh=None, row_axis: Optional[str] = "data"):
         import jax
         import jax.numpy as jnp
@@ -286,7 +286,6 @@ class DeviceNeighborSampler:
         self.fanouts = list(fanouts)
         self.seed = int(seed)
         self.use_pallas = bool(use_pallas)
-        self.interpret = bool(interpret)
         self.base_key = jax.random.PRNGKey(self.seed)
         # device tables: one CSR (+ optional edge-time table) per etype;
         # passed into the jitted step as a pytree argument, placed once.
@@ -443,7 +442,7 @@ class DeviceNeighborSampler:
                     nbr, eid, mask = nbr_sample(
                         t["row_ptr"], t["col_idx"], t["edge_id"], dst_ids,
                         key, fanout=pe.fanout, use_pallas=self.use_pallas,
-                        interpret=self.interpret, bits=bits)
+                        bits=bits)
                 if exclude is not None and pe.etype in exclude:
                     hit = _pair_exclusion_hit(nbr, dst_ids,
                                               *exclude[pe.etype])

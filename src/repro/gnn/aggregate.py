@@ -4,12 +4,13 @@ The (num_dst, fanout, dim) masked reduction is the message-passing
 hot-spot; ``repro.kernels.seg_aggr`` provides the Pallas TPU kernel and
 these jnp forms are its oracle (and the CPU execution path).
 
-Kernel routing is config-driven: ``GSConfig``'s ``gnn.use_pallas`` /
-``gnn.pallas_interpret`` flow into ``GSgnnModel`` and
-``gnn_apply_blocks`` scopes them around the layer stack via
-``routing(...)``.  The legacy mutable global survives only as the
-*default* routing behind ``set_use_pallas`` (back-compat shim for code
-that predates the config keys).
+Kernel routing is config-driven: ``GSConfig``'s ``gnn.use_pallas``
+flows into ``GSgnnModel`` and ``gnn_apply_blocks`` scopes it around the
+layer stack via ``routing(...)``.  The legacy mutable global survives
+only as the *default* routing behind ``set_use_pallas`` (back-compat
+shim for code that predates the config key).  Whether a routed kernel
+is compiled or interpreted follows the backend
+(``repro.kernels.backend``).
 """
 from __future__ import annotations
 
@@ -20,76 +21,56 @@ import jax
 import jax.numpy as jnp
 
 # routing stack: [-1] is active; [0] is the process default (the old
-# set_use_pallas global).  Entries are (use_pallas, interpret).
-_ROUTING = [(False, True)]
+# set_use_pallas global)
+_ROUTING = [False]
 
 
 @contextlib.contextmanager
-def routing(use_pallas: Optional[bool] = None,
-            interpret: Optional[bool] = None):
+def routing(use_pallas: Optional[bool] = None):
     """Scope kernel routing for a model apply; ``None`` inherits the
     enclosing scope (so hand-built models keep the process default)."""
-    cur = _ROUTING[-1]
-    _ROUTING.append((cur[0] if use_pallas is None else bool(use_pallas),
-                     cur[1] if interpret is None else bool(interpret)))
+    _ROUTING.append(_ROUTING[-1] if use_pallas is None else bool(use_pallas))
     try:
         yield
     finally:
         _ROUTING.pop()
 
 
-def set_use_pallas(flag: bool, interpret: bool = True):
+def set_use_pallas(flag: bool):
     """Back-compat shim: set the *default* routing.  New code should set
-    ``gnn.use_pallas`` / ``gnn.pallas_interpret`` in GSConfig (routing
-    then scopes per model apply) instead of flipping process state."""
-    _ROUTING[0] = (bool(flag), bool(interpret))
+    ``gnn.use_pallas`` in GSConfig (routing then scopes per model apply)
+    instead of flipping process state."""
+    _ROUTING[0] = bool(flag)
 
 
 def pallas_enabled() -> bool:
-    return _ROUTING[-1][0]
+    return _ROUTING[-1]
 
 
-def _interpret() -> bool:
-    return _ROUTING[-1][1]
+def _fanout_sum(nbr_h, m):
+    """Contract the fanout axis as a batched matvec (einsum) instead of
+    materializing the masked (n, f, d) product — ~6x faster on CPU XLA,
+    same math.  HIGHEST pins the sum to f32 whatever the process's
+    default matmul precision (on TPU v5e the default form trains to
+    bitwise-identical losses, so the pin costs nothing there)."""
+    return jnp.einsum("nfd,nf->nd", nbr_h, m,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def masked_mean(nbr_h, mask):
-    """nbr_h: (n, f, d), mask: (n, f) -> (n, d).  The jnp form contracts
-    the fanout axis as a batched matvec (einsum) instead of materializing
-    the masked (n, f, d) product — ~6x faster on CPU XLA, same math."""
+    """nbr_h: (n, f, d), mask: (n, f) -> (n, d)."""
     if pallas_enabled():
         from repro.kernels.seg_aggr.ops import seg_aggr
-        return seg_aggr(nbr_h, mask, reduce="mean", interpret=_interpret())
+        return seg_aggr(nbr_h, mask, reduce="mean")
     m = mask.astype(nbr_h.dtype)
-    s = jnp.einsum("nfd,nf->nd", nbr_h, m)
-    return s / jnp.maximum(m.sum(axis=1), 1.0)[:, None]
+    return _fanout_sum(nbr_h, m) / jnp.maximum(m.sum(axis=1), 1.0)[:, None]
 
 
 def masked_sum(nbr_h, mask):
     if pallas_enabled():
         from repro.kernels.seg_aggr.ops import seg_aggr
-        return seg_aggr(nbr_h, mask, reduce="sum", interpret=_interpret())
-    return jnp.einsum("nfd,nf->nd", nbr_h, mask.astype(nbr_h.dtype))
-
-
-def fanout_indices(offset: int, num_dst: int, fanout: int):
-    """Row indices of an edge block's sampled neighbors in the frontier:
-    the sampler lays them out contiguously at ``offset`` (see
-    repro.core.sampling), so the gather index block is a reshaped iota."""
-    idx = offset + jnp.arange(num_dst * fanout, dtype=jnp.int32)
-    return idx.reshape(num_dst, fanout)
-
-
-def gather_masked_agg(table, idx, mask, reduce: str = "mean"):
-    """Fused ``table[idx]`` gather + masked fanout reduce: (N, d) x (n, f)
-    -> (n, d) without materializing the (n, f, d) intermediate in HBM
-    (the Pallas ``gather_seg_aggr`` kernel; jnp oracle on CPU)."""
-    if pallas_enabled():
-        from repro.kernels.seg_aggr.ops import gather_seg_aggr
-        return gather_seg_aggr(table, idx, mask, reduce=reduce,
-                               interpret=_interpret())
-    from repro.kernels.seg_aggr.ref import gather_seg_aggr_ref
-    return gather_seg_aggr_ref(table, idx, mask, reduce)
+        return seg_aggr(nbr_h, mask, reduce="sum")
+    return _fanout_sum(nbr_h, mask.astype(nbr_h.dtype))
 
 
 def masked_max(nbr_h, mask):

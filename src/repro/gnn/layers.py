@@ -19,9 +19,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro.gnn.aggregate import (fanout_indices, gather_masked_agg,
-                                 masked_mean, masked_softmax, masked_sum,
-                                 pallas_enabled)
+from repro.gnn.aggregate import masked_mean, masked_softmax, masked_sum
 from repro.gnn.schema import LayerSchema
 
 
@@ -33,14 +31,10 @@ def _nbr_rows(src_h, em):
 
 
 def _agg_fanout(src_h, em, mask, reduce: str):
-    """Aggregate an edge block's fanout rows.  With the Pallas kernels
-    enabled this is the fused gather_seg_aggr (no (num_dst, fanout, d)
-    intermediate in HBM); on the default XLA path the old contiguous
-    slice + masked reduce is kept — a static slice is free, whereas a row
-    gather is not guaranteed to simplify back to one."""
-    if pallas_enabled():
-        idx = fanout_indices(em.src_offset, em.num_dst, em.fanout)
-        return gather_masked_agg(src_h[em.src_t], idx, mask, reduce)
+    """Aggregate an edge block's fanout rows: the sampler lays them out
+    contiguously in the frontier (see repro.core.sampling), so they are a
+    free static slice, reduced by the masked mean or sum (the Pallas
+    ``seg_aggr`` kernel when routed)."""
     nbr = _nbr_rows(src_h, em)
     return (masked_mean if reduce == "mean" else masked_sum)(nbr, mask)
 

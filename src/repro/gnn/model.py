@@ -28,10 +28,9 @@ class GSgnnModel:
     ntypes: Tuple[str, ...] = ()
     etypes: Tuple[Tuple[str, str, str], ...] = ()  # (ekey, src_t, dst_t)
     feat_dims: Tuple[Tuple[str, int], ...] = ()    # per-ntype input dim
-    # Pallas kernel routing (gnn.use_pallas / gnn.pallas_interpret in
-    # GSConfig); None inherits the process default (set_use_pallas shim)
+    # Pallas kernel routing (gnn.use_pallas in GSConfig); None inherits
+    # the process default (set_use_pallas shim)
     use_pallas: Optional[bool] = None
-    pallas_interpret: Optional[bool] = None
 
 
 def init_gnn_model(rng, model: GSgnnModel):
@@ -69,7 +68,7 @@ def gnn_apply_blocks(params, model: GSgnnModel, schema: BlockSchema,
     """Run the GNN over an MFG mini-batch; returns seed embeddings."""
     from repro.gnn.aggregate import routing
     _, apply_fn = LAYERS[model.kind]
-    with routing(model.use_pallas, model.pallas_interpret):
+    with routing(model.use_pallas):
         h = input_encode(params, arrays["feats"])
         for l, lsch in enumerate(schema.layers):
             arrays_l = {"masks": arrays["masks"][l]}
@@ -85,8 +84,7 @@ def model_meta_from_graph(graph, kind: str, hidden: int, num_layers: int,
                           nheads: int = 4,
                           extra_feat_dims: Optional[Dict[str, int]] = None,
                           feat_field: str = "feat",
-                          use_pallas: Optional[bool] = None,
-                          pallas_interpret: Optional[bool] = None
+                          use_pallas: Optional[bool] = None
                           ) -> GSgnnModel:
     from repro.gnn.schema import ekey
     feat_dims = {nt: graph.feat_dim(nt, feat_field) for nt in graph.ntypes
@@ -98,5 +96,5 @@ def model_meta_from_graph(graph, kind: str, hidden: int, num_layers: int,
         ntypes=tuple(graph.ntypes),
         etypes=tuple((ekey(et), et[0], et[2]) for et in graph.etypes),
         feat_dims=tuple(sorted(feat_dims.items())),
-        use_pallas=use_pallas, pallas_interpret=pallas_interpret,
+        use_pallas=use_pallas,
     )

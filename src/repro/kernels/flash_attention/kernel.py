@@ -11,11 +11,14 @@ BQ*Dh + BK*Dh + BQ*BK + BQ*Dh(acc) floats ≈ 0.5 MiB at 128/128/128.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -64,7 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     B, H, S, Dh = q.shape
     Sk = k.shape[2]
     bq = min(bq, S)
@@ -89,5 +92,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

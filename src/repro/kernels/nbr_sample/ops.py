@@ -7,12 +7,13 @@ the kernel/oracle map words onto CSR segments.  A config seed therefore
 fully determines the sample stream, on any backend, inside or outside
 jit.
 
-On CPU the kernel body executes in interpret mode (correctness path);
-on TPU set interpret=False for the compiled kernel.
+With ``use_pallas`` the kernel compiles on a TPU backend and runs the
+Pallas interpreter on the CPU (``repro.kernels.backend``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,7 @@ from repro.kernels.nbr_sample.ref import nbr_sample_ref, segment_bounds_ref
 @functools.partial(jax.jit,
                    static_argnames=("fanout", "use_pallas", "interpret"))
 def nbr_sample(row_ptr, col_idx, edge_id, dst_ids, key, *, fanout: int,
-               use_pallas: bool = False, interpret: bool = True,
+               use_pallas: bool = False, interpret: Optional[bool] = None,
                bits=None):
     """Draw ``fanout`` in-neighbors per dst id from a device CSR.
 

@@ -1,5 +1,4 @@
-from repro.kernels.seg_aggr.ops import gather_seg_aggr, seg_aggr
-from repro.kernels.seg_aggr.ref import gather_seg_aggr_ref, seg_aggr_ref
+from repro.kernels.seg_aggr.ops import seg_aggr
+from repro.kernels.seg_aggr.ref import seg_aggr_ref
 
-__all__ = ["seg_aggr", "seg_aggr_ref", "gather_seg_aggr",
-           "gather_seg_aggr_ref"]
+__all__ = ["seg_aggr", "seg_aggr_ref"]

@@ -1,19 +1,20 @@
 """Pallas TPU kernel: masked fixed-fanout neighbor aggregation.
 
-Layout: nbr (n, fanout, d) with validity mask (n, fanout) — the padded
-MFG block produced by the sampler.  Tiling: the grid runs over
-(n / BLK_N, d / BLK_D); the full fanout axis stays inside the block
-(fanout <= 64 in every sampler config), so one block's working set is
-BLK_N * fanout * BLK_D * 4B  (128 * 32 * 128 * 4 = 2 MiB < VMEM)
-and the reduction over fanout is a single VPU pass — no HBM revisits.
+``seg_aggr`` reduces an already-gathered ``(n, fanout, d)`` block.  The
+grid runs over ``(n / BLK_N, d / BLK_D)``; the full fanout axis stays in
+the block, so one block's working set is ``BLK_N * fanout * BLK_D * 4B``
+(128 * 32 * 128 * 4 = 2 MiB) and the fanout reduction is one VPU pass.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve_interpret
 
 BLK_N = 128
 BLK_D = 128
@@ -30,7 +31,7 @@ def _seg_aggr_kernel(nbr_ref, mask_ref, out_ref, *, reduce: str):
 
 
 def seg_aggr_pallas(nbr, mask, reduce: str = "mean", *,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     n, f, d = nbr.shape
     blk_n = min(BLK_N, n)
     blk_d = min(BLK_D, d)
@@ -44,57 +45,5 @@ def seg_aggr_pallas(nbr, mask, reduce: str = "mean", *,
         ],
         out_specs=pl.BlockSpec((blk_n, blk_d), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, d), nbr.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(nbr, mask)
-
-
-def _gather_seg_aggr_kernel(idx_ref, mask_ref, table_ref, out_ref, *,
-                            reduce: str):
-    t = table_ref[...].astype(jnp.float32)         # (N, BLK_D)
-    idx = idx_ref[...]                             # (BLK_N, F) int32
-    m = mask_ref[...]                              # (BLK_N, F) bool
-    bn, f = idx.shape
-    # gather the fanout rows straight from the VMEM-resident table tile;
-    # the (BLK_N, F, BLK_D) slab lives only in registers/VMEM, never HBM
-    rows = jnp.take(t, idx.reshape(-1), axis=0).reshape(bn, f, -1)
-    if reduce == "max":
-        s = jnp.where(m[:, :, None], rows, -jnp.inf).max(axis=1)
-        s = jnp.where(m.any(axis=1, keepdims=True), s, 0.0)
-    else:
-        mf = m.astype(jnp.float32)
-        s = jnp.sum(rows * mf[:, :, None], axis=1)
-        if reduce == "mean":
-            s = s / jnp.maximum(jnp.sum(mf, axis=1), 1.0)[:, None]
-    out_ref[...] = s.astype(out_ref.dtype)
-
-
-def gather_seg_aggr_pallas(table, idx, mask, reduce: str = "mean", *,
-                           interpret: bool = True):
-    """Fused feature-gather + masked fanout reduction.
-
-    table: (N, d) frontier hidden rows; idx: (n, f) int32 row indices;
-    mask: (n, f) -> (n, d).  The grid runs over (n / BLK_N, d / BLK_D) and
-    each program keeps the *full row axis* of its table d-tile in VMEM
-    (N * BLK_D * 4B), gathering fanout rows in-register.  This targets MFG
-    frontier tables, which are minibatch-sized (N ~ 1e3-1e4 rows -> a few
-    MiB per tile); graph-scale feature tables take the XLA device-gather
-    path in repro.core.feature_store instead.
-    """
-    N, d = table.shape
-    n, f = idx.shape
-    assert mask.shape == (n, f), (mask.shape, idx.shape)
-    blk_n = min(BLK_N, n)
-    blk_d = min(BLK_D, d)
-    grid = (pl.cdiv(n, blk_n), pl.cdiv(d, blk_d))
-    return pl.pallas_call(
-        functools.partial(_gather_seg_aggr_kernel, reduce=reduce),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((blk_n, f), lambda i, j: (i, 0)),
-            pl.BlockSpec((blk_n, f), lambda i, j: (i, 0)),
-            pl.BlockSpec((N, blk_d), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((blk_n, blk_d), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, d), table.dtype),
-        interpret=interpret,
-    )(idx.astype(jnp.int32), mask, table)

@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the seg_aggr kernels."""
+"""Pure-jnp oracle for the seg_aggr kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -13,22 +13,3 @@ def seg_aggr_ref(nbr, mask, reduce: str = "mean"):
     if reduce == "mean":
         return s / jnp.maximum(m.sum(axis=1), 1.0)
     raise ValueError(reduce)
-
-
-def gather_seg_aggr_ref(table, idx, mask, reduce: str = "mean"):
-    """Fused row-gather + masked fanout reduction (the oracle).
-
-    table: (N, d) frontier rows; idx: (n, f) int row indices into table;
-    mask: (n, f) validity -> (n, d).  Equivalent to
-    ``seg_aggr_ref(table[idx], mask)`` but the kernel version never
-    materializes the (n, f, d) gathered intermediate in HBM.
-    Fully-masked rows produce 0 in every reduce mode.
-    """
-    n, f = idx.shape
-    rows = jnp.take(table, idx.reshape(-1), axis=0).reshape(n, f, -1)
-    if reduce == "max":
-        neg = jnp.full_like(rows, -jnp.inf)
-        s = jnp.where(mask[..., None], rows, neg).max(axis=1)
-        return jnp.where(mask.any(axis=1, keepdims=True), s,
-                         jnp.zeros_like(s)).astype(table.dtype)
-    return seg_aggr_ref(rows, mask, reduce)

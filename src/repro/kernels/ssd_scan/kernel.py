@@ -16,10 +16,13 @@ stays in jnp — see ops.ssd_forward.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve_interpret
 
 
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref):
@@ -49,7 +52,7 @@ def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref):
     st_ref[0, 0] = st
 
 
-def ssd_chunk_pallas(x, dt, A, Bh, Ch, *, interpret: bool = True):
+def ssd_chunk_pallas(x, dt, A, Bh, Ch, *, interpret: Optional[bool] = None):
     """x: (BN,H,Q,P) dt: (BN,H,Q) A: (H,) Bh/Ch: (BN,H,Q,N)
     -> y_diag (BN,H,Q,P), states (BN,H,P,N)."""
     BN, H, Q, P = x.shape
@@ -73,5 +76,5 @@ def ssd_chunk_pallas(x, dt, A, Bh, Ch, *, interpret: bool = True):
             jax.ShapeDtypeStruct((BN, H, Q, P), x.dtype),
             jax.ShapeDtypeStruct((BN, H, P, N), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, A, Bh, Ch)

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,7 @@ from repro.kernels.ssd_scan.kernel import ssd_chunk_pallas
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_forward(x, dt, A, Bm, Cm, D=None, chunk: int = 64,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """x: (B,S,H,P) dt: (B,S,H) A: (H,) Bm/Cm: (B,S,G,N).
     Returns (y (B,S,H,P), final_state (B,H,P,N))."""
     Bz, S, H, P = x.shape

@@ -1,76 +1,85 @@
-"""Pallas TPU kernel: static-capacity unique over a sorted id vector.
+"""Pallas TPU kernel: distinct ranks of a sorted id vector.
 
-The sort itself stays an XLA prologue (``ops.unique_rows`` argsorts and
-hands the kernel the sorted values plus each element's sorted position),
-mirroring ``nbr_sample``'s segment-bounds prologue.  The kernel runs as
-a single program with the whole vector VMEM-resident — frontiers are
-minibatch-sized (tens of KiB), the same residency stance as the
-``nbr_sample`` tables — and does three VPU passes:
+The sort stays an XLA prologue and the compaction an XLA epilogue
+(``ops.unique_rows``); the kernel computes, for each sorted element, its
+rank among the distinct values: a run start (``s[i] != s[i-1]``) and an
+inclusive prefix sum of the run starts, minus one.
 
-- run starts (``s[i] != s[i-1]``) and a cumsum give each sorted element
-  its distinct rank;
-- ``inv`` is one gather of the (capacity-clipped) ranks through the
-  inverse sort order;
-- ``uniq`` compacts the first element of each run to its slot via a
-  vectorized binary search over the non-decreasing rank vector
-  (O(cap log n) gathers, no dynamic scatter — TPU-friendly).
+The vector is laid out as ``(rows, 128)`` and walked in blocks of
+``RANK_ROWS`` rows over a sequential grid.  Within a block the previous
+element comes from a lane roll (and a sublane roll for lane 0), and the
+prefix sum is log-step: seven lane roll-and-add passes, then the same
+over the row totals along sublanes.  Two SMEM scalars carry the last
+value and the running distinct count from one block to the next.  The
+pad past ``n`` sorts after every real id, so it never changes a real
+element's rank.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
+
+LANES = 128
+RANK_ROWS = 256
 
 
-def _unique_rows_kernel(s_ref, invord_ref, uniq_ref, inv_ref, count_ref):
-    s = s_ref[...]                             # (n,) int32, sorted
+def _prefix_sum(x, axis: int):
+    """Inclusive prefix sum along ``axis`` by log-step roll-and-add."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    shift = 1
+    while shift < x.shape[axis]:
+        x = x + jnp.where(pos >= shift, pltpu.roll(x, shift, axis), 0)
+        shift *= 2
+    return x
+
+
+def _rank_kernel(s_ref, rank_ref, carry_ref):
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        carry_ref[0] = jnp.int32(-1)      # previous value: ids are >= 0
+        carry_ref[1] = jnp.int32(0)       # distinct values so far
+
+    x = s_ref[...]                        # (rows, 128) sorted int32
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    left = pltpu.roll(x, 1, 1)            # x[r, l-1]; lane 0 wraps
+    up = pltpu.roll(left, 1, 0)           # lane 0: x[r-1, 127]
+    prev = jnp.where(lane == 0, up, left)
+    prev = jnp.where((lane == 0) & (row == 0), carry_ref[0], prev)
+    firsts = (x != prev).astype(jnp.int32)
+    in_row = _prefix_sum(firsts, 1)
+    totals = jnp.broadcast_to(in_row[:, LANES - 1:], x.shape)
+    before = _prefix_sum(totals, 0) - totals
+    rank_ref[...] = in_row + before + carry_ref[1] - 1
+    carry_ref[1] = carry_ref[1] + jnp.sum(firsts)
+    carry_ref[0] = jnp.max(x)
+
+
+def sorted_ranks_pallas(s, *, interpret: Optional[bool] = None):
+    """s: (n,) int32 non-negative ids sorted ascending -> (n,) int32 rank
+    of each element among the distinct values (0-based)."""
     n = s.shape[0]
-    cap = uniq_ref.shape[0]
-    firsts = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32), (s[1:] != s[:-1]).astype(jnp.int32)])
-    rank = jnp.cumsum(firsts) - 1
-    count = rank[n - 1] + 1
-    slot = jnp.minimum(rank, cap - 1)
-    inv_ref[...] = jnp.take(slot, invord_ref[...])
-    # first sorted position of each rank j: binary search in the
-    # non-decreasing rank vector (2-D iota per the TPU lowering rules)
-    j = jax.lax.broadcasted_iota(jnp.int32, (cap, 1), 0)[:, 0]
-    lo = jnp.zeros((cap,), jnp.int32)
-    hi = jnp.full((cap,), n, jnp.int32)
-
-    def step(_, lh):
-        lo, hi = lh
-        mid = (lo + hi) // 2
-        below = jnp.take(rank, jnp.clip(mid, 0, n - 1)) < j
-        return jnp.where(below, mid + 1, lo), jnp.where(below, hi, mid)
-
-    lo, _ = jax.lax.fori_loop(0, max(1, n - 1).bit_length() + 1, step,
-                              (lo, hi))
-    first = jnp.clip(lo, 0, n - 1)
-    uniq_ref[...] = jnp.where(j < count, jnp.take(s, first), 0)
-    count_ref[...] = jnp.reshape(count, (1,))
-
-
-def unique_rows_pallas(s, invord, *, capacity: int, interpret: bool = True):
-    """s: (n,) int32 sorted ids; invord: (n,) int32 sorted position of
-    each original element -> (uniq (capacity,), inv (n,), count (1,))."""
-    n = s.shape[0]
-    return pl.pallas_call(
-        _unique_rows_kernel,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((capacity,), lambda i: (0,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((capacity,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(s, invord)
+    rows = -(-n // LANES)
+    blk = min(RANK_ROWS, -(-rows // 8) * 8)
+    rows_pad = -(-rows // blk) * blk
+    pad = rows_pad * LANES - n
+    s2 = jnp.pad(s.astype(jnp.int32), (0, pad),
+                 constant_values=jnp.iinfo(jnp.int32).max)
+    rank = pl.pallas_call(
+        _rank_kernel,
+        grid=(rows_pad // blk,),
+        in_specs=[pl.BlockSpec((blk, LANES), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((blk, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows_pad, LANES), jnp.int32),
+        scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=resolve_interpret(interpret),
+    )(s2.reshape(rows_pad, LANES))
+    return rank.reshape(-1)[:n]

@@ -9,17 +9,18 @@ overflow (more distinct values than slots); callers branch to the
 un-deduplicated exchange in that case, so results stay bit-identical
 for every input.
 
-On CPU the kernel body executes in interpret mode (correctness path);
-on TPU set interpret=False for the compiled kernel.
+The Pallas path compiles on a TPU backend and runs the interpreter on
+the CPU (``repro.kernels.backend``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.unique_rows.kernel import unique_rows_pallas
+from repro.kernels.unique_rows.kernel import sorted_ranks_pallas
 from repro.kernels.unique_rows.ref import unique_rows_ref
 
 
@@ -60,7 +61,7 @@ def _unique_rows_dense(ids, capacity: int, universe: int):
                    static_argnames=("capacity", "universe", "use_pallas",
                                     "interpret"))
 def unique_rows(ids, *, capacity: int, universe=None,
-                use_pallas: bool = False, interpret: bool = True):
+                use_pallas: bool = False, interpret: Optional[bool] = None):
     """Static-capacity unique.
 
     ids: (n,) non-negative int row ids ->
@@ -76,17 +77,26 @@ def unique_rows(ids, *, capacity: int, universe=None,
     (unless the universe is so large the prefix sum would cost more
     than the sort; see ``DENSE_UNIVERSE_MAX``); results are
     bit-identical either way.
+
+    ``use_pallas``: the distinct ranks of the sorted ids come from the
+    Pallas kernel; the sort before it and the compaction after it stay
+    XLA.  Bit-identical to the oracle.
     """
     ids = ids.astype(jnp.int32)
     if use_pallas:
         n = ids.shape[0]
         order = jnp.argsort(ids)               # XLA prologue (the sort)
         s = jnp.take(ids, order)
-        invord = jnp.zeros((n,), jnp.int32).at[order].set(
-            jnp.arange(n, dtype=jnp.int32))
-        uniq, inv, count = unique_rows_pallas(
-            s, invord, capacity=capacity, interpret=interpret)
-        return uniq, inv, count[0]
+        rank = sorted_ranks_pallas(s, interpret=interpret)
+        count = rank[n - 1] + 1
+        slot = jnp.minimum(rank, capacity - 1)
+        inv = jnp.zeros((n,), jnp.int32).at[order].set(slot)
+        # first value of each slot's run, as in the oracle (overflow
+        # included): min-scatter, then pad the slots past count with 0
+        uniq = jnp.full((capacity,), jnp.iinfo(jnp.int32).max,
+                        jnp.int32).at[slot].min(s)
+        uniq = jnp.where(jnp.arange(capacity) < count, uniq, 0)
+        return uniq, inv, count
     if universe is not None and int(universe) <= DENSE_UNIVERSE_MAX:
         return _unique_rows_dense(ids, capacity, int(universe))
     return unique_rows_ref(ids, capacity)

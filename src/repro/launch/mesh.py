@@ -6,18 +6,25 @@ importing this module never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the code places arrays with
+    NamedSharding/GSPMD and explicit shard_map, not sharding-in-types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-process mesh over whatever devices exist (smoke/e2e runs)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _make_mesh((n, 1), ("data", "model"))
 
 
 def make_data_mesh(num_shards: int = 0):
@@ -36,7 +43,7 @@ def make_data_mesh(num_shards: int = 0):
             f"data_parallel={num_shards} but only {avail} device(s) exist; "
             f"on CPU export XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{num_shards} before starting python")
-    return jax.make_mesh((n,), ("data",))
+    return _make_mesh((n,), ("data",))
 
 
 def dp_axes(mesh) -> tuple:

@@ -117,8 +117,7 @@ def build_model_and_embeds(cfg: GSConfig, graph: HeteroGraph,
         graph, cfg.gnn.model, hidden=cfg.gnn.hidden,
         num_layers=cfg.gnn.num_layers, nheads=cfg.gnn.nheads,
         extra_feat_dims={nt: cfg.gnn.sparse_embed_dim for nt in sparse},
-        feat_field=ff, use_pallas=cfg.gnn.use_pallas,
-        pallas_interpret=cfg.gnn.pallas_interpret)
+        feat_field=ff, use_pallas=cfg.gnn.use_pallas)
     return model, sparse
 
 
@@ -168,7 +167,6 @@ class TaskRunner:
         return DeviceNeighborSampler(
             graph, self.cfg.gnn.fanout, seed=self.hp.seed,
             use_pallas=self.cfg.gnn.use_pallas,
-            interpret=self.cfg.gnn.pallas_interpret,
             mesh=self.mesh, row_axis=self._row_axis)
 
     def _split_rng(self):
@@ -635,10 +633,9 @@ def _serve_ready(cfg: GSConfig) -> GSConfig:
     return GSConfig.from_dict(raw)
 
 
-def run_config(cfg: GSConfig, inference: bool = False,
-               serve: bool = False) -> dict:
-    """The single programmatic entry point: resolve the config, build the
-    graph, dispatch through the registry, train / infer / serve, persist."""
+def build_runner(cfg: GSConfig, serve: bool = False) -> TaskRunner:
+    """Resolve the config, build the graph, and assemble the registered
+    task runner (restored from ``output.restore_model_path`` if set)."""
     if serve:
         cfg = _serve_ready(cfg)
     cfg = cfg.resolved()
@@ -649,6 +646,15 @@ def run_config(cfg: GSConfig, inference: bool = False,
     runner = TASK_REGISTRY[cfg.task](cfg, graph)
     if cfg.output.restore_model_path:
         runner.restore(cfg.output.restore_model_path)
+    return runner
+
+
+def run_config(cfg: GSConfig, inference: bool = False,
+               serve: bool = False) -> dict:
+    """The single programmatic entry point: resolve the config, build the
+    graph, dispatch through the registry, train / infer / serve, persist."""
+    runner = build_runner(cfg, serve=serve)
+    cfg = runner.cfg
     if serve:
         result = runner.serve()
     elif inference:

@@ -113,6 +113,8 @@ class StreamingEpochEngine:
         self._fns = None
         self._eval_fns = None
         self._val_staged = None
+        # one array per epoch run: the train loss of each step, in order
+        self.step_losses: List[np.ndarray] = []
 
     # ------------------------------------------------------------------
     def _stage(self, epoch: int):
@@ -205,6 +207,7 @@ class StreamingEpochEngine:
                 tr._sparse_unpack(state)
                 losses = np.concatenate(
                     [np.asarray(p).reshape(-1) for p in parts])
+                self.step_losses.append(losses)
                 rec = {"epoch": eidx, "loss": float(losses.mean()),
                        "epoch_time_s": time.time() - t0}
                 if ev is not None:

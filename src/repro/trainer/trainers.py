@@ -447,7 +447,7 @@ class _TrainerBase:
         embeddings (in-batch scores) and the SpotTarget pair list.  This
         is the GiGL/AGL minibatch-data-parallel layout — no resharding
         of the interleaved MFG frontier ever happens."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.gnn.schema import schema_of_plan
         from repro.trainer.task_programs import device_capability
@@ -522,7 +522,7 @@ class _TrainerBase:
             local_step, mesh=mesh,
             in_specs=(repl, repl, repl, repl, repl, repl, P("data")),
             out_specs=(repl, repl, repl, repl, repl, P("data")),
-            check_rep=False)
+            check_vma=False)
 
     def _make_device_fns_alltoall(self, plan, batch_size, store_nts,
                                   sparse_nts, collect_stats: bool = False):
@@ -570,7 +570,7 @@ class _TrainerBase:
         arrival (exact per row — one owner per row means the psum never
         adds two nonzero bf16 values).
         """
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.common.sharding import (RaggedExchange, dedup_gather,
                                            unique_count, wire_row_bytes)
@@ -842,11 +842,11 @@ class _TrainerBase:
         step_sm = shard_map(
             local_step, mesh=mesh, in_specs=common + (P("data"),),
             out_specs=(repl, repl, repl, sparse_specs, repl, P("data")),
-            check_rep=False)
+            check_vma=False)
         epoch_sm = shard_map(
             local_epoch, mesh=mesh, in_specs=common + (P(None, "data"),),
             out_specs=(repl, repl, repl, sparse_specs, repl),
-            check_rep=False)
+            check_vma=False)
         probe_sm = None
         if collect_stats:
             # measured-exchange probe: run one presample and return every
@@ -859,7 +859,7 @@ class _TrainerBase:
             probe_sm = shard_map(
                 probe, mesh=mesh,
                 in_specs=(table_specs, csr_specs, P("data"), repl),
-                out_specs=P("data"), check_rep=False)
+                out_specs=P("data"), check_vma=False)
         return step_sm, epoch_sm, probe_sm
 
     @staticmethod
@@ -999,7 +999,7 @@ class _TrainerBase:
         every shard runs the complete local program on its slice.
         Shards meet only at the global masked-mean rescale, the gradient
         psum, and the sparse-embedding scatter psum."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.sampling import plan_sample, shard_host_perms
         from repro.gnn.schema import ekey, schema_of_plan
@@ -1070,7 +1070,7 @@ class _TrainerBase:
             local_epoch, mesh=mesh,
             in_specs=(repl, repl, repl, repl, repl, repl, xs_spec),
             out_specs=(repl, repl, repl, repl, repl),
-            check_rep=False)
+            check_vma=False)
 
         # which ntype's frontier rows each etype's mask/Δt block indexes
         layer_dst = [{ekey(pe.etype): pe.etype[2] for pe in pl.edges}

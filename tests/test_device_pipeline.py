@@ -96,13 +96,14 @@ def test_device_ids_rejects_int32_overflow():
 
 
 def test_pallas_toggle_layer_parity(mag):
-    """sage layer output must be identical with the fused Pallas path
-    (interpret mode) and the default slice+reduce path."""
+    """rgcn layer output must be identical with the Pallas seg_aggr
+    kernel (interpreted on the CPU backend) and the default XLA
+    reduce."""
     from repro.gnn import aggregate
     trainer = _trainer(mag, store=DeviceFeatureStore(mag))
     batch = next(iter(_loader(mag, host_features=False)))
     default = np.asarray(trainer.embed_batch(batch)["paper"])
-    aggregate.set_use_pallas(True, interpret=True)
+    aggregate.set_use_pallas(True)
     try:
         fused = np.asarray(trainer.embed_batch(batch)["paper"])
     finally:

@@ -25,7 +25,7 @@ from repro.data import make_mag_like
 from repro.gnn.model import model_meta_from_graph
 from repro.trainer import (GSgnnAccEvaluator, GSgnnData, GSgnnNodeDataLoader,
                            GSgnnNodeTrainer)
-from repro.trainer.epoch_engine import _chunk_bounds
+from repro.trainer.epoch_engine import StreamingEpochEngine, _chunk_bounds
 
 _ROOT = os.path.join(os.path.dirname(__file__), "..")
 
@@ -100,6 +100,23 @@ def test_host_engine_matches_legacy_per_batch_loop(mag):
     # identical (seed, epoch)-keyed draws; only XLA fusion differs
     # between the scanned epoch program and the per-batch step
     np.testing.assert_allclose(_losses(hist), legacy, rtol=1e-4)
+
+
+@pytest.mark.parametrize("chunks", [1, 3])
+def test_engine_step_losses_match_legacy_per_batch_loop(mag, chunks):
+    """The engine keeps each step's loss, in step order across chunks;
+    they average to the epoch loss and follow the per-batch loop."""
+    engine = StreamingEpochEngine(_nc_trainer(mag), _nc_loader(mag),
+                                  epoch_chunks=chunks)
+    hist = engine.run(2)
+    legacy_tr = _nc_trainer(mag)
+    loader = _nc_loader(mag)
+    legacy = [[legacy_tr.fit_batch(b)[0] for b in loader] for _ in range(2)]
+    assert [len(s) for s in engine.step_losses] == [4, 4]
+    np.testing.assert_allclose([s.mean() for s in engine.step_losses],
+                               _losses(hist), rtol=1e-6)
+    np.testing.assert_allclose(np.stack(engine.step_losses), legacy,
+                               rtol=1e-4)
 
 
 def test_engine_second_fit_continues_epoch_stream(mag):

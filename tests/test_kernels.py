@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs jnp oracles."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpreted on the CPU) vs jnp
+oracles."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,8 +7,7 @@ import pytest
 
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.nbr_sample import nbr_sample, segment_bounds_ref
-from repro.kernels.seg_aggr import (gather_seg_aggr, gather_seg_aggr_ref,
-                                    seg_aggr, seg_aggr_ref)
+from repro.kernels.seg_aggr import seg_aggr, seg_aggr_ref
 from repro.kernels.ssd_scan import ssd_forward, ssd_ref_sequential
 
 RNG = np.random.default_rng(0)
@@ -37,58 +37,58 @@ def test_seg_aggr_all_masked_rows():
     np.testing.assert_allclose(np.asarray(out), 0.0)
 
 
-# ---------------------------------------------------------------------------
-# gather_seg_aggr: fused row-gather + masked fanout reduce
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("shape", [
-    (64, 16, 4, 32),      # small, everything divides
-    (500, 130, 7, 96),    # odd fanout, n/d not multiples of the block
-    (1000, 256, 32, 128), # block-sized tiles
-    (37, 10, 1, 300),     # fanout 1, wide d
-    (128, 1, 5, 16),      # single dst row
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("reduce", ["mean", "sum", "max"])
-def test_gather_seg_aggr(shape, dtype, reduce):
-    N, n, f, d = shape
-    table = jnp.asarray(RNG.normal(size=(N, d)), dtype)
-    idx = jnp.asarray(RNG.integers(0, N, (n, f)), jnp.int32)
-    m = jnp.asarray(RNG.random((n, f)) < 0.7)
-    out = gather_seg_aggr(table, idx, m, reduce)
-    ref = gather_seg_aggr_ref(table, idx, m, reduce)
-    assert out.shape == (n, d) and out.dtype == dtype
-    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("reduce", ["mean", "sum", "max"])
-def test_gather_seg_aggr_empty_neighbor_rows(reduce):
-    """Fully-masked rows (isolated nodes) must emit exactly 0."""
-    table = jnp.asarray(RNG.normal(size=(32, 24)), jnp.float32)
-    idx = jnp.asarray(RNG.integers(0, 32, (10, 6)), jnp.int32)
+@pytest.mark.parametrize("reduce", ["mean", "sum"])
+def test_seg_aggr_partly_masked_rows(reduce):
+    """Fully-masked rows (isolated nodes) emit exactly 0 and a row with
+    one valid neighbor emits that neighbor, in both reduce modes."""
+    x = jnp.asarray(RNG.normal(size=(10, 6, 24)), jnp.float32)
     m = np.ones((10, 6), bool)
-    m[3] = False          # one isolated node
-    m[7, 1:] = False      # one node with a single neighbor
-    m = jnp.asarray(m)
-    out = np.asarray(gather_seg_aggr(table, idx, m, reduce))
-    np.testing.assert_allclose(out[3], 0.0)
-    ref = np.asarray(gather_seg_aggr_ref(table, idx, m, reduce))
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    m[3] = False
+    m[7, 1:] = False
+    out = np.asarray(seg_aggr(x, jnp.asarray(m), reduce))
+    np.testing.assert_array_equal(out[3], 0.0)
+    np.testing.assert_allclose(out[7], np.asarray(x[7, 0]), rtol=1e-6)
+    np.testing.assert_allclose(
+        out, np.asarray(seg_aggr_ref(x, jnp.asarray(m), reduce)),
+        rtol=1e-5, atol=1e-5)
 
 
-def test_gather_seg_aggr_matches_unfused():
-    """gather+seg_aggr fused == gather then seg_aggr (mean/sum)."""
-    table = jnp.asarray(RNG.normal(size=(200, 48)), jnp.float32)
-    idx = jnp.asarray(RNG.integers(0, 200, (33, 9)), jnp.int32)
-    m = jnp.asarray(RNG.random((33, 9)) < 0.5)
-    rows = jnp.take(table, idx.reshape(-1), axis=0).reshape(33, 9, 48)
-    for reduce in ("mean", "sum"):
-        fused = gather_seg_aggr(table, idx, m, reduce)
-        unfused = seg_aggr(rows, m, reduce)
-        np.testing.assert_allclose(np.asarray(fused), np.asarray(unfused),
-                                   rtol=1e-5, atol=1e-5)
+@pytest.mark.parametrize("shape", [(16, 4, 8), (130, 7, 96), (256, 32, 128),
+                                   (100, 1, 300), (1, 64, 16)])
+@pytest.mark.parametrize("reduce", ["mean", "sum"])
+def test_seg_aggr_grad_sweep(shape, reduce):
+    """The kernel's VJP (the oracle's transpose) gives the oracle's
+    gradient at every swept shape, as training through
+    ``gnn.use_pallas`` needs."""
+    n, f, d = shape
+    x = jnp.asarray(RNG.normal(size=shape), jnp.float32)
+    m = jnp.asarray(RNG.random((n, f)) < 0.7)
+    w = jnp.asarray(RNG.normal(size=(n, d)), jnp.float32)
+    g = [jax.grad(lambda v: (fn(v, m, reduce) * w).sum())(x)
+         for fn in (seg_aggr, seg_aggr_ref)]
+    np.testing.assert_allclose(np.asarray(g[0]), np.asarray(g[1]),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_seg_aggr_grad_matches_oracle():
+    x = jnp.asarray(RNG.normal(size=(12, 4, 16)), jnp.float32)
+    m = jnp.asarray(RNG.random((12, 4)) < 0.7)
+    g = [jax.grad(lambda v: fn(v, m, "mean").sum())(x)
+         for fn in (seg_aggr, seg_aggr_ref)]
+    np.testing.assert_allclose(np.asarray(g[0]), np.asarray(g[1]),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_interpret_follows_the_backend(monkeypatch):
+    """Kernels interpret on the CPU, compile on a TPU, and refuse an
+    explicit interpret=True on a TPU."""
+    from repro.kernels import backend
+    assert backend.resolve_interpret(None) is True
+    assert backend.resolve_interpret(False) is False
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "tpu")
+    assert backend.resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="TPU"):
+        backend.resolve_interpret(True)
 
 
 # ---------------------------------------------------------------------------
@@ -113,7 +113,7 @@ def _random_csr(num_dst, max_deg, num_src, rng, force_zero=()):
     (10, 1, 1),        # single dst / fanout 1
 ])
 def test_nbr_sample_kernel_matches_ref(shape):
-    """Kernel (interpret) and jnp oracle consume the same uniform bits,
+    """Kernel (interpreted) and jnp oracle consume the same uniform bits,
     so their draws must be bit-identical."""
     num_dst, n, f = shape
     rng = np.random.default_rng(3)

@@ -75,7 +75,8 @@ def test_perf_knobs_preserve_loss():
     cfg = get_smoke_config("granite-3-2b")
     params = init_params(cfg, jax.random.PRNGKey(0))
     batch = concrete_inputs(cfg, InputShape("t", 64, 2, "train"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
     with mesh:
         base = float(make_loss_fn(cfg)(params, batch)[0])
         for kw in ({"seq_parallel": True}, {"shard_activations": True},

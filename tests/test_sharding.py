@@ -98,7 +98,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.common.sharding import RaggedExchange, shard_rows
 
@@ -127,7 +127,7 @@ def run_case(rows, dim, n_req, idx):
 
     f = jax.jit(shard_map(
         local, mesh=mesh, in_specs=(P("data"), P("data"), P("data")),
-        out_specs=(P("data"), P("data")), check_rep=False))
+        out_specs=(P("data"), P("data")), check_vma=False))
     sh = NamedSharding(mesh, P("data"))
     out, acc = f(tbl, jax.device_put(idx, sh), jax.device_put(grads, sh))
     # gather must be bit-identical to the replicated (padded) gather
@@ -225,7 +225,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.common.sharding import (RaggedExchange, dedup_gather,
                                    dedup_capacity, shard_rows)
@@ -252,7 +252,7 @@ def gathers(rows, dim, idx, capacity=None, wire=None):
 
     f = jax.jit(shard_map(
         local, mesh=mesh, in_specs=(P("data"), P("data")),
-        out_specs=(P("data"), P("data")), check_rep=False))
+        out_specs=(P("data"), P("data")), check_vma=False))
     sh = NamedSharding(mesh, P("data"))
     ded, plain = f(tbl, jax.device_put(idx, sh))
     rows_pad = tbl.shape[0]
@@ -319,7 +319,7 @@ def _traced(dimw):
     t = jnp.zeros((64, dimw), jnp.float32)
     return str(jax.make_jaxpr(shard_map(
         local, mesh=mesh, in_specs=(P("data"), P("data")),
-        out_specs=P("data"), check_rep=False))(t, idx))
+        out_specs=P("data"), check_vma=False))(t, idx))
 
 results["narrow_payload_static_plain"] = (
     "cond" not in _traced(3) and "cond" in _traced(16))

@@ -1,6 +1,6 @@
 """The static-capacity unique primitive behind shard_dedup
 (``kernels/unique_rows`` — docs/pipeline.md §3e): jnp oracle semantics,
-oracle-vs-Pallas-kernel bitwise parity (interpret mode on CPU), and the
+oracle-vs-Pallas-kernel bitwise parity (interpreted on the CPU), and the
 overflow contract the in-jit exchange fallback relies on."""
 import jax
 import jax.numpy as jnp
