@@ -386,6 +386,7 @@ class DeviceNeighborSampler:
         frontier = {nt: jnp.asarray(seeds[nt]).astype(jnp.int32)
                     for nt, _ in plan.seed_counts}
         from repro.kernels.nbr_sample import nbr_sample
+        from repro.trainer import tracing
         if seed_keyed and dp is not None:
             raise ValueError("seed_keyed draws and dp sharding are "
                              "mutually exclusive — the dp bit-stream "
@@ -444,9 +445,10 @@ class DeviceNeighborSampler:
                         key, fanout=pe.fanout, use_pallas=self.use_pallas,
                         bits=bits)
                 if exclude is not None and pe.etype in exclude:
-                    hit = _pair_exclusion_hit(nbr, dst_ids,
-                                              *exclude[pe.etype])
-                    mask = mask & ~hit
+                    with tracing.scope("spot_target"):
+                        hit = _pair_exclusion_hit(nbr, dst_ids,
+                                                  *exclude[pe.etype])
+                        mask = mask & ~hit
                 ek = "___".join(pe.etype)
                 masks[ek] = mask
                 if pe.has_delta_t:

@@ -67,16 +67,19 @@ def gnn_apply_blocks(params, model: GSgnnModel, schema: BlockSchema,
                      arrays) -> Dict[str, jax.Array]:
     """Run the GNN over an MFG mini-batch; returns seed embeddings."""
     from repro.gnn.aggregate import routing
+    from repro.trainer import tracing
     _, apply_fn = LAYERS[model.kind]
     with routing(model.use_pallas):
-        h = input_encode(params, arrays["feats"])
+        with tracing.scope("encode"):
+            h = input_encode(params, arrays["feats"])
         for l, lsch in enumerate(schema.layers):
             arrays_l = {"masks": arrays["masks"][l]}
             if arrays.get("delta_t") and l < len(arrays["delta_t"]):
                 arrays_l["delta_t"] = arrays["delta_t"][l]
-            h = apply_fn(params["layers"][l], lsch, arrays_l, h)
-            if l < schema.num_layers - 1:
-                h = {nt: jax.nn.relu(v) for nt, v in h.items()}
+            with tracing.scope(f"gnn.layer{l}"):
+                h = apply_fn(params["layers"][l], lsch, arrays_l, h)
+                if l < schema.num_layers - 1:
+                    h = {nt: jax.nn.relu(v) for nt, v in h.items()}
     return h
 
 

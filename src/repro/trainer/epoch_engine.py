@@ -40,6 +40,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import AsyncCheckpointWriter
+from repro.trainer import tracing
 
 
 def _chunk_bounds(nb: int, k: int) -> List[tuple]:
@@ -121,12 +122,13 @@ class StreamingEpochEngine:
         """Build + place epoch ``epoch``'s blocks.  Pure host + transfer
         work — called right after a chunk dispatch so it overlaps the
         device running the current epoch."""
-        xs = self.loader.epoch_blocks(epoch=epoch)
-        if self._fns is None:
-            self._fns = self.trainer._engine_fns_for(self.loader, xs)
-        if self._fns.get("prepare") is not None:
-            xs = self._fns["prepare"](xs)
-        return self._fns["put"](xs)
+        with tracing.span("stage_epoch"):
+            xs = self.loader.epoch_blocks(epoch=epoch)
+            if self._fns is None:
+                self._fns = self.trainer._engine_fns_for(self.loader, xs)
+            if self._fns.get("prepare") is not None:
+                xs = self._fns["prepare"](xs)
+            return self._fns["put"](xs)
 
     def _stage_val(self):
         """Stage the validation epoch once (epoch-0 keyed: the val
@@ -155,6 +157,13 @@ class StreamingEpochEngine:
 
     # ------------------------------------------------------------------
     def run(self, num_epochs: int = 1) -> List[dict]:
+        """Train ``num_epochs`` epochs; returns the trainer's history.
+        Each piece of host work is a ``tracing`` span inside
+        ``engine.run``."""
+        with tracing.span("engine.run"):
+            return self._run(num_epochs)
+
+    def _run(self, num_epochs: int) -> List[dict]:
         tr = self.trainer
         loader = self.loader
         if getattr(loader, "sample_on_device", False):
@@ -181,8 +190,10 @@ class StreamingEpochEngine:
                 parts = []
                 next_staged = None
                 for ci, (a, b) in enumerate(_chunk_bounds(nb, k)):
-                    xs = tm(lambda v: v[a:b], staged)
-                    out = fns["epoch"](*carry, tables, csr, xs)
+                    with tracing.span("slice_chunk"):
+                        xs = tm(lambda v: v[a:b], staged)
+                    with tracing.span("dispatch_epoch"):
+                        out = fns["epoch"](*carry, tables, csr, xs)
                     carry, losses = tuple(out[:4]), out[4]
                     parts.append(losses)
                     if ci == 0 and e + 1 < num_epochs:
@@ -191,35 +202,43 @@ class StreamingEpochEngine:
                         next_staged = self._stage(eidx + 1)
                 ev = None
                 if self._do_device_eval():
-                    if self._val_staged is None:
-                        self._stage_val()
-                    # reads the post-epoch params (no donation): queued
-                    # behind the last chunk, fetched as two scalars below
-                    ev = self._eval_fns["epoch"](carry[0], carry[3],
-                                                 tables, csr,
-                                                 self._val_staged)
+                    with tracing.span("eval_epoch"):
+                        if self._val_staged is None:
+                            self._stage_val()
+                        # reads the post-epoch params (no donation):
+                        # queued behind the last chunk, fetched as two
+                        # scalars below
+                        ev = self._eval_fns["epoch"](carry[0], carry[3],
+                                                     tables, csr,
+                                                     self._val_staged)
                 snap = None
                 if self.checkpoint is not None:
                     # jitted device copy, dispatched BEFORE the next
                     # epoch's donation can recycle the live buffers
-                    snap = tr._snapshot_fn()(carry)
+                    with tracing.span("checkpoint"):
+                        snap = tr._snapshot_fn()(carry)
                 tr.params, tr.opt_state, tr.stepno, state = carry
                 tr._sparse_unpack(state)
-                losses = np.concatenate(
-                    [np.asarray(p).reshape(-1) for p in parts])
+                with tracing.span("fetch_losses"):
+                    losses = np.concatenate(
+                        [np.asarray(p).reshape(-1) for p in parts])
                 self.step_losses.append(losses)
                 rec = {"epoch": eidx, "loss": float(losses.mean()),
                        "epoch_time_s": time.time() - t0}
                 if ev is not None:
-                    evaluator = tr.evaluator
-                    evaluator.reset()
-                    evaluator.merge(np.asarray(ev[0]), np.asarray(ev[1]))
-                    rec[evaluator.name] = evaluator.value()
+                    with tracing.span("eval_epoch"):
+                        evaluator = tr.evaluator
+                        evaluator.reset()
+                        evaluator.merge(np.asarray(ev[0]),
+                                        np.asarray(ev[1]))
+                        rec[evaluator.name] = evaluator.value()
                 elif self.val_loader is not None and tr.evaluator is not None:
-                    rec[tr.evaluator.name] = tr.evaluate(self.val_loader)
+                    with tracing.span("eval_epoch"):
+                        rec[tr.evaluator.name] = tr.evaluate(self.val_loader)
                 tr.history.append(rec)
                 if self.checkpoint is not None:
-                    self._submit_checkpoint(snap, writer)
+                    with tracing.span("checkpoint"):
+                        self._submit_checkpoint(snap, writer)
                 if self.verbose:
                     print(rec)
                 staged = next_staged

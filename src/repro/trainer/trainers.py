@@ -50,6 +50,7 @@ from repro.gnn.decoders import (decoder_apply, init_decoder, lp_score,
 from repro.gnn.model import GSgnnModel, gnn_apply_blocks, init_gnn_model
 from repro.optim import adamw
 from repro.optim.schedules import cosine_schedule
+from repro.trainer import tracing
 
 # device-resident validation draws its sampling steps from a dedicated
 # range of the counter-based stream so eval subgraphs never collide with
@@ -259,6 +260,14 @@ class _TrainerBase:
             if nt in feat_grads:
                 self.sparse_embeds[nt].apply_sparse_grad(ids, feat_grads[nt])
 
+    def _adamw(self, grads, opt_state, params, stepno):
+        """The dense update of one step: AdamW at the step's cosine
+        learning rate."""
+        with tracing.scope("adamw"):
+            lr = cosine_schedule(stepno, 10, 10000, self.lr)
+            return self.optimizer.update(grads, opt_state, params, stepno,
+                                         lr)
+
     def _loss_and_out(self, params, feats, batch):
         raise NotImplementedError
 
@@ -278,10 +287,13 @@ class _TrainerBase:
             # device-resident path: gather raw features from the resident
             # tables by the batch's int32 frontier indices, in-jit (fuses
             # with the input encoder; tables take no gradient)
-            gathered = {nt: tables[nt][gather_idx[nt]] for nt in gather_idx}
+            with tracing.scope("gather.features"):
+                gathered = {nt: tables[nt][gather_idx[nt]]
+                            for nt in gather_idx}
             arr["feats"] = {**gathered, **feats}
             emb = gnn_apply_blocks(params["gnn"], self.model, schema, arr)
-            return head(params, emb, aux_in)
+            with tracing.scope("head"):
+                return head(params, emb, aux_in)
         return loss_fn
 
     def _make_step(self, schema, roles=None, neg_shape=None, k=0):
@@ -293,9 +305,7 @@ class _TrainerBase:
             (loss, out), (gp, gf) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True)(
                     params, feats, arrays, aux_in, gather_idx, tables)
-            lr = cosine_schedule(stepno, 10, 10000, self.lr)
-            params, opt_state = self.optimizer.update(gp, opt_state, params,
-                                                      stepno, lr)
+            params, opt_state = self._adamw(gp, opt_state, params, stepno)
             return params, opt_state, stepno + 1, loss, out, gf
 
         # donate params/opt_state/stepno: they are consumed and returned
@@ -381,13 +391,16 @@ class _TrainerBase:
 
         def step(params, opt_state, stepno, sparse_state, tables, csr,
                  blocks):
-            seeds, aux_in, exclude = program.expand(blocks, stepno)
-            masks, dts, frontier = sampler.sample(csr, plan, seeds, stepno,
-                                                  exclude=exclude)
+            with tracing.scope("expand"):
+                seeds, aux_in, exclude = program.expand(blocks, stepno)
+            with tracing.scope("sample"):
+                masks, dts, frontier = sampler.sample(
+                    csr, plan, seeds, stepno, exclude=exclude)
             arrays = {"masks": masks, "delta_t": dts}
             gather_idx = {nt: frontier[nt] for nt in store_nts}
-            feats = {nt: sparse_state[nt][0][frontier[nt]]
-                     for nt in sparse_nts}
+            with tracing.scope("gather.embeddings"):
+                feats = {nt: sparse_state[nt][0][frontier[nt]]
+                         for nt in sparse_nts}
             # data-parallel note (GSPMD path): the blocks arrive sharded
             # over the "data" mesh axis; the loss is a *global* masked
             # mean, so the SPMD partitioner inserts the gradient
@@ -396,13 +409,13 @@ class _TrainerBase:
             (loss, out), (gp, gf) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True)(
                     params, feats, arrays, aux_in, gather_idx, tables)
-            lr = cosine_schedule(stepno, 10, 10000, self.lr)
-            params, opt_state = self.optimizer.update(gp, opt_state, params,
-                                                      stepno, lr)
+            params, opt_state = self._adamw(gp, opt_state, params, stepno)
             sparse_state = dict(sparse_state)
-            for nt in sparse_nts:
-                sparse_state[nt] = _sparse_adagrad(
-                    *sparse_state[nt], frontier[nt], gf[nt], sparse_lrs[nt])
+            with tracing.scope("sparse_adagrad"):
+                for nt in sparse_nts:
+                    sparse_state[nt] = _sparse_adagrad(
+                        *sparse_state[nt], frontier[nt], gf[nt],
+                        sparse_lrs[nt])
             if mesh is not None:
                 from repro.common.sharding import constrain_replicated
                 params = constrain_replicated(mesh, params)
@@ -484,14 +497,18 @@ class _TrainerBase:
 
         def local_step(params, opt_state, stepno, sparse_state, tables,
                        csr, blocks):
-            seeds, aux_in, exclude = program.expand(blocks, stepno, dp=dp)
-            masks, dts, frontier = sampler.sample(
-                csr, local_plan, seeds, stepno, exclude=exclude,
-                dp=dp, seed_maps=seed_maps)
+            with tracing.scope("expand"):
+                seeds, aux_in, exclude = program.expand(blocks, stepno,
+                                                        dp=dp)
+            with tracing.scope("sample"):
+                masks, dts, frontier = sampler.sample(
+                    csr, local_plan, seeds, stepno, exclude=exclude,
+                    dp=dp, seed_maps=seed_maps)
             arrays = {"masks": masks, "delta_t": dts}
             gather_idx = {nt: frontier[nt] for nt in store_nts}
-            feats = {nt: sparse_state[nt][0][frontier[nt]]
-                     for nt in sparse_nts}
+            with tracing.scope("gather.embeddings"):
+                feats = {nt: sparse_state[nt][0][frontier[nt]]
+                         for nt in sparse_nts}
 
             def global_loss(p, f):
                 # loss_fn yields the LOCAL masked mean; rescale so the
@@ -507,14 +524,13 @@ class _TrainerBase:
                 global_loss, argnums=(0, 1), has_aux=True)(params, feats)
             gp = jax.lax.psum(gp, "data")
             loss = jax.lax.psum(loss, "data")
-            lr = cosine_schedule(stepno, 10, 10000, self.lr)
-            params, opt_state = self.optimizer.update(gp, opt_state,
-                                                      params, stepno, lr)
+            params, opt_state = self._adamw(gp, opt_state, params, stepno)
             sparse_state = dict(sparse_state)
-            for nt in sparse_nts:
-                sparse_state[nt] = _sparse_adagrad_dp(
-                    *sparse_state[nt], frontier[nt], gf[nt],
-                    sparse_lrs[nt], "data")
+            with tracing.scope("sparse_adagrad"):
+                for nt in sparse_nts:
+                    sparse_state[nt] = _sparse_adagrad_dp(
+                        *sparse_state[nt], frontier[nt], gf[nt],
+                        sparse_lrs[nt], "data")
             return params, opt_state, stepno + 1, sparse_state, loss, out
 
         repl = P()
@@ -655,45 +671,50 @@ class _TrainerBase:
 
         def presample(tables, csr, blocks, stepno):
             sink = [] if collect_stats else None
-            seeds, aux_in, exclude = program.expand(blocks, stepno, dp=dp)
-            masks, dts, frontier = sampler.sample(
-                csr, local_plan, seeds, stepno, exclude=exclude,
-                dp=dp, seed_maps=seed_maps, shard=shard_arg,
-                shard_dedup=dedup, stats_sink=sink)
-            store_feats = {}
-            for nt in store_nts:
-                if store_sh[nt] and dedup:
-                    store_feats[nt] = dedup_gather(
-                        frontier[nt], tables[nt], axis_name="data",
-                        n_shards=n, rows_per_shard=tables[nt].shape[0],
-                        wire_dtype=wire_dt,
-                        stats_sink=sink).astype(store_dt[nt])
-                elif store_sh[nt]:
-                    if sink is not None:
-                        sink.append({
-                            "requests": frontier[nt].shape[0],
-                            "distinct": unique_count(frontier[nt]),
-                            "capacity": frontier[nt].shape[0],
-                            "payload_bytes": wire_row_bytes(tables[nt],
-                                                            wire_dt),
-                            "fits": jnp.int32(1)})
-                    ex = RaggedExchange(
-                        frontier[nt], axis_name="data", n_shards=n,
-                        rows_per_shard=tables[nt].shape[0])
-                    store_feats[nt] = ex.gather(
-                        tables[nt],
-                        wire_dtype=wire_dt).astype(store_dt[nt])
-                else:
-                    store_feats[nt] = tables[nt][frontier[nt]]
+            with tracing.scope("expand"):
+                seeds, aux_in, exclude = program.expand(blocks, stepno,
+                                                        dp=dp)
+            with tracing.scope("sample"):
+                masks, dts, frontier = sampler.sample(
+                    csr, local_plan, seeds, stepno, exclude=exclude,
+                    dp=dp, seed_maps=seed_maps, shard=shard_arg,
+                    shard_dedup=dedup, stats_sink=sink)
+            with tracing.scope("gather.features"):
+                store_feats = {}
+                for nt in store_nts:
+                    if store_sh[nt] and dedup:
+                        store_feats[nt] = dedup_gather(
+                            frontier[nt], tables[nt], axis_name="data",
+                            n_shards=n, rows_per_shard=tables[nt].shape[0],
+                            wire_dtype=wire_dt,
+                            stats_sink=sink).astype(store_dt[nt])
+                    elif store_sh[nt]:
+                        if sink is not None:
+                            sink.append({
+                                "requests": frontier[nt].shape[0],
+                                "distinct": unique_count(frontier[nt]),
+                                "capacity": frontier[nt].shape[0],
+                                "payload_bytes": wire_row_bytes(tables[nt],
+                                                                wire_dt),
+                                "fits": jnp.int32(1)})
+                        ex = RaggedExchange(
+                            frontier[nt], axis_name="data", n_shards=n,
+                            rows_per_shard=tables[nt].shape[0])
+                        store_feats[nt] = ex.gather(
+                            tables[nt],
+                            wire_dtype=wire_dt).astype(store_dt[nt])
+                    else:
+                        store_feats[nt] = tables[nt][frontier[nt]]
             # sparse routings stay un-deduplicated: the exchange must be
             # reusable for the backward scatter (duplicate grad rows sum
             # through the routing) and ride the scan carry with a static
             # shape — dedup's overflow cond cannot change the carry.
-            sparse_route = {
-                nt: RaggedExchange(frontier[nt], axis_name="data",
-                                   n_shards=n,
-                                   rows_per_shard=sparse_rps[nt])
-                for nt in sparse_nts if sparse_sh[nt]}
+            with tracing.scope("gather.embeddings"):
+                sparse_route = {
+                    nt: RaggedExchange(frontier[nt], axis_name="data",
+                                       n_shards=n,
+                                       rows_per_shard=sparse_rps[nt])
+                    for nt in sparse_nts if sparse_sh[nt]}
             if sink is not None:
                 for nt in sparse_nts:
                     if sparse_sh[nt]:
@@ -720,11 +741,13 @@ class _TrainerBase:
             arrays = {"masks": pf["masks"], "delta_t": pf["dts"]}
             aux_in = pf["aux_in"]
             feats = dict(pf["store_feats"])
-            for nt in sparse_nts:
-                feats[nt] = (pf["sparse_route"][nt].gather(
-                                 sparse_state[nt][0], wire_dtype=wire_dt)
-                             if sparse_sh[nt]
-                             else sparse_state[nt][0][pf["sparse_ids"][nt]])
+            with tracing.scope("gather.embeddings"):
+                for nt in sparse_nts:
+                    feats[nt] = (
+                        pf["sparse_route"][nt].gather(sparse_state[nt][0],
+                                                      wire_dtype=wire_dt)
+                        if sparse_sh[nt]
+                        else sparse_state[nt][0][pf["sparse_ids"][nt]])
 
             def global_loss(p, f):
                 # loss_fn yields the LOCAL masked mean; rescale so the
@@ -738,9 +761,7 @@ class _TrainerBase:
                 global_loss, argnums=(0, 1), has_aux=True)(params, feats)
             gp = jax.lax.psum(gp, "data")
             loss = jax.lax.psum(loss, "data")
-            lr = cosine_schedule(stepno, 10, 10000, self.lr)
-            params, opt_state = self.optimizer.update(gp, opt_state,
-                                                      params, stepno, lr)
+            params, opt_state = self._adamw(gp, opt_state, params, stepno)
             gf_sp = {nt: gf[nt] for nt in sparse_nts}
             return params, opt_state, stepno + 1, loss, out, gf_sp
 
@@ -751,15 +772,16 @@ class _TrainerBase:
             unchanged), which makes the pipeline's zero-initialised
             pending stage safe to apply."""
             sparse_state = dict(sparse_state)
-            for nt in sparse_nts:
-                if sparse_sh[nt]:
-                    sparse_state[nt] = _sparse_adagrad_shard(
-                        *sparse_state[nt], routes[nt], gf_sp[nt],
-                        sparse_lrs[nt])
-                else:
-                    sparse_state[nt] = _sparse_adagrad_dp(
-                        *sparse_state[nt], ids[nt], gf_sp[nt],
-                        sparse_lrs[nt], "data")
+            with tracing.scope("sparse_adagrad"):
+                for nt in sparse_nts:
+                    if sparse_sh[nt]:
+                        sparse_state[nt] = _sparse_adagrad_shard(
+                            *sparse_state[nt], routes[nt], gf_sp[nt],
+                            sparse_lrs[nt])
+                    else:
+                        sparse_state[nt] = _sparse_adagrad_dp(
+                            *sparse_state[nt], ids[nt], gf_sp[nt],
+                            sparse_lrs[nt], "data")
             return sparse_state
 
         def compute(params, opt_state, stepno, sparse_state, pf):
@@ -963,19 +985,19 @@ class _TrainerBase:
             arrays = {"masks": xs["masks"], "delta_t": xs["delta_t"]}
             gather_idx = {nt: xs["idx"][nt] for nt in store_nts}
             feats = dict(xs["feats"])
-            for nt in sparse_nts:
-                feats[nt] = sparse_state[nt][0][xs["idx"][nt]]
+            with tracing.scope("gather.embeddings"):
+                for nt in sparse_nts:
+                    feats[nt] = sparse_state[nt][0][xs["idx"][nt]]
             (loss, out), (gp, gf) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True)(
                     params, feats, arrays, xs["aux"], gather_idx, tables)
-            lr = cosine_schedule(stepno, 10, 10000, self.lr)
-            params, opt_state = self.optimizer.update(gp, opt_state, params,
-                                                      stepno, lr)
+            params, opt_state = self._adamw(gp, opt_state, params, stepno)
             sparse_state = dict(sparse_state)
-            for nt in sparse_nts:
-                sparse_state[nt] = _sparse_adagrad(
-                    *sparse_state[nt], xs["idx"][nt], gf[nt],
-                    sparse_lrs[nt])
+            with tracing.scope("sparse_adagrad"):
+                for nt in sparse_nts:
+                    sparse_state[nt] = _sparse_adagrad(
+                        *sparse_state[nt], xs["idx"][nt], gf[nt],
+                        sparse_lrs[nt])
             if mesh is not None:
                 from repro.common.sharding import constrain_replicated
                 params = constrain_replicated(mesh, params)
@@ -1036,8 +1058,9 @@ class _TrainerBase:
             arrays = {"masks": xsb["masks"], "delta_t": xsb["delta_t"]}
             gather_idx = {nt: xsb["idx"][nt] for nt in store_nts}
             feats = dict(xsb["feats"])
-            for nt in sparse_nts:
-                feats[nt] = sparse_state[nt][0][xsb["idx"][nt]]
+            with tracing.scope("gather.embeddings"):
+                for nt in sparse_nts:
+                    feats[nt] = sparse_state[nt][0][xsb["idx"][nt]]
             aux_in = xsb["aux"]
 
             def global_loss(p, f):
@@ -1053,14 +1076,13 @@ class _TrainerBase:
                 global_loss, argnums=(0, 1), has_aux=True)(params, feats)
             gp = jax.lax.psum(gp, "data")
             loss = jax.lax.psum(loss, "data")
-            lr = cosine_schedule(stepno, 10, 10000, self.lr)
-            params, opt_state = self.optimizer.update(gp, opt_state,
-                                                      params, stepno, lr)
+            params, opt_state = self._adamw(gp, opt_state, params, stepno)
             sparse_state = dict(sparse_state)
-            for nt in sparse_nts:
-                sparse_state[nt] = _sparse_adagrad_dp(
-                    *sparse_state[nt], xsb["idx"][nt], gf[nt],
-                    sparse_lrs[nt], "data")
+            with tracing.scope("sparse_adagrad"):
+                for nt in sparse_nts:
+                    sparse_state[nt] = _sparse_adagrad_dp(
+                        *sparse_state[nt], xsb["idx"][nt], gf[nt],
+                        sparse_lrs[nt], "data")
             return params, opt_state, stepno + 1, sparse_state, loss, out
 
         local_epoch = self._make_device_epoch(local_step)
@@ -1165,9 +1187,11 @@ class _TrainerBase:
 
             def body(carry, xsb):
                 blk, step = xsb
-                seeds, aux_in, exclude = program.expand(blk, step)
-                masks, dts, frontier = sampler.sample(csr, plan, seeds,
-                                                      step, exclude=exclude)
+                with tracing.scope("expand"):
+                    seeds, aux_in, exclude = program.expand(blk, step)
+                with tracing.scope("sample"):
+                    masks, dts, frontier = sampler.sample(
+                        csr, plan, seeds, step, exclude=exclude)
                 arrays = {"masks": masks, "delta_t": dts}
                 gather_idx = {nt: frontier[nt] for nt in store_nts}
                 feats = {nt: sparse_state[nt][0][frontier[nt]]
