@@ -543,34 +543,21 @@ def _pair_exclusion_hit(nbr, dst_ids, ex_src, ex_dst):
     ``(nbr[i, j], dst_ids[i])`` coincide with an excluded
     ``(ex_src, ex_dst)`` target pair.
 
-    A dense broadcast compare is O(n * f * E) — at LP scale (frontier
-    ~1e5 rows, E ~1e3 pairs) that is ~1e9 bool ops per layer and
-    dominated the whole device step.  Instead, rank both endpoints
-    against the sorted exclusion lists (ranks are equality-preserving
-    for *member* values) and pack the rank pair into one int32 code:
-    codes fit in ``(E+1)^2`` regardless of graph size — the combined
-    ``src * |V| + dst`` code the host sampler uses would overflow int32
-    on large graphs — and membership becomes one searchsorted over E
-    sorted codes: O((n*f + E) log E).
+    Exact membership by compares alone, O(n * f * E) of them and no
+    gather, sort or search: the ``n * f`` queries lie flat on the minor
+    (lane) axis and the E pairs on the major one, which the reduction
+    ORs away elementwise, so on a TPU the ``(E, n * f)`` compare fuses
+    into the reduction and never reaches HBM.  Padding pairs of ``-1``
+    match no node id, duplicate pairs are harmless, and E = 0 gives all
+    false.
     """
     import jax.numpy as jnp
-    e = int(ex_src.shape[0])
-    if e == 0 or e * (e + 2) >= 2 ** 31:
-        # degenerate / huge exclusion lists: dense compare fallback
-        hit = (nbr[:, :, None] == ex_src[None, None, :]) \
-            & (dst_ids[:, None, None] == ex_dst[None, None, :])
-        return hit.any(axis=-1)
-    ss = jnp.sort(ex_src)
-    sd = jnp.sort(ex_dst)
-    rs = jnp.searchsorted(ss, nbr)                       # (n, f)
-    ms = ss[jnp.clip(rs, 0, e - 1)] == nbr               # src is a member
-    rd = jnp.searchsorted(sd, dst_ids)                   # (n,)
-    md = sd[jnp.clip(rd, 0, e - 1)] == dst_ids           # dst is a member
-    code = rd[:, None] * (e + 1) + rs
-    ex_code = jnp.sort(jnp.searchsorted(sd, ex_dst) * (e + 1)
-                       + jnp.searchsorted(ss, ex_src))
-    p = jnp.searchsorted(ex_code, code)
-    return ms & md[:, None] & (ex_code[jnp.clip(p, 0, e - 1)] == code)
+    n, f = nbr.shape
+    q_src = nbr.reshape(-1)
+    q_dst = jnp.broadcast_to(dst_ids[:, None], (n, f)).reshape(-1)
+    hit = (ex_src[:, None] == q_src[None, :]) \
+        & (ex_dst[:, None] == q_dst[None, :])
+    return hit.any(axis=0).reshape(n, f)
 
 
 def _extend_row_map(maps, pl_layer: PlanLayer, nt: str, recipe,

@@ -246,25 +246,44 @@ def test_device_sampler_stream_is_counter_based():
     assert any((f0[nt] != g0[nt]).any() for nt in f0)
 
 
-def test_pair_exclusion_hit_matches_dense_compare():
-    """The searchsorted SpotTarget membership test (rank-pair codes,
-    int32-safe at any graph size) must agree exactly with the dense
-    broadcast compare, including -1 pads and duplicate pairs."""
+@pytest.mark.parametrize("n,f,e,v,kind", [
+    (40, 3, 9, 25, "random"),
+    (200, 5, 64, 50, "random"),
+    (64, 4, 1, 10, "random"),
+    (32, 3, 0, 10, "random"),          # no target pairs: all false
+    (48, 4, 16, 10, "padding"),        # a list of -1 pads alone
+    (16, 4, 1024, 40, "random"),       # a whole batch of pairs
+    (24, 5, 12, 30, "members"),        # every query is a target pair
+    (4, 2, 46341, 20, "random"),       # e * (e + 2) >= 2**31
+])
+def test_pair_exclusion_hit_matches_dense_compare(n, f, e, v, kind):
+    """The in-jit SpotTarget membership test agrees exactly with a
+    numpy broadcast compare, including -1 pads and duplicate pairs."""
     import jax.numpy as jnp
     from repro.core.sampling import _pair_exclusion_hit
-    rng = np.random.default_rng(7)
-    for n, f, e, v in ((40, 3, 9, 25), (200, 5, 64, 50), (64, 4, 1, 10)):
-        nbr = jnp.asarray(rng.integers(0, v, (n, f)), jnp.int32)
-        dst = jnp.asarray(rng.integers(0, v, n), jnp.int32)
-        ex_s = rng.integers(0, v, e).astype(np.int32)
-        ex_d = rng.integers(0, v, e).astype(np.int32)
-        if e > 4:
-            ex_s[-2:] = -1
-            ex_d[-2:] = -1                    # padding convention
-            ex_s[0], ex_d[0] = ex_s[1], ex_d[1]   # duplicate pair
-        dense = ((np.asarray(nbr)[:, :, None] == ex_s[None, None, :])
-                 & (np.asarray(dst)[:, None, None] == ex_d[None, None, :])
-                 ).any(-1)
-        fast = np.asarray(_pair_exclusion_hit(
-            nbr, dst, jnp.asarray(ex_s), jnp.asarray(ex_d)))
-        np.testing.assert_array_equal(fast, dense)
+    rng = np.random.default_rng(7 + n + e)
+    nbr = rng.integers(0, v, (n, f)).astype(np.int32)
+    dst = rng.integers(0, v, n).astype(np.int32)
+    ex_s = rng.integers(0, v, e).astype(np.int32)
+    ex_d = rng.integers(0, v, e).astype(np.int32)
+    if kind == "padding":
+        ex_s[:] = -1
+        ex_d[:] = -1
+    elif kind == "members":
+        # every sampled pair, shuffled in among e random ones
+        perm = rng.permutation(e + n * f)
+        ex_s = np.concatenate([ex_s, nbr.reshape(-1)])[perm]
+        ex_d = np.concatenate([ex_d, np.repeat(dst, f)])[perm]
+    elif e > 4:
+        ex_s[-2:] = -1
+        ex_d[-2:] = -1                    # padding convention
+        ex_s[0], ex_d[0] = ex_s[1], ex_d[1]   # duplicate pair
+    dense = ((nbr[:, :, None] == ex_s[None, None, :])
+             & (dst[:, None, None] == ex_d[None, None, :])).any(-1)
+    if kind == "members":
+        assert dense.all()
+    fast = np.asarray(_pair_exclusion_hit(
+        jnp.asarray(nbr), jnp.asarray(dst), jnp.asarray(ex_s),
+        jnp.asarray(ex_d)))
+    assert fast.shape == (n, f) and fast.dtype == np.bool_
+    np.testing.assert_array_equal(fast, dense)

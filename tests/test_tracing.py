@@ -19,6 +19,7 @@ LOWERINGS = {"single": {"data_parallel": 1},
 TASKS = ("node_classification", "link_prediction")
 NAME = re.compile(r'loc\("([^"]*)"')
 WRAPPED = re.compile(r"^[A-Za-z_]\w*\((.*)\)$")
+HLO_OP = re.compile(r"= \S+ (\w[\w-]*)\(.*op_name=\"([^\"]*)\"")
 
 
 def _raw(task, **hp):
@@ -61,15 +62,19 @@ def _scopes_in(names):
     return found
 
 
-def _lowered_names(task, **hp):
+def _lowered_epoch(task, **hp):
     runner, loader = _runner(task, **hp)
     tr = runner.trainer
     xs = loader.epoch_blocks(epoch=0)
     fns = tr._engine_fns_for(loader, xs)
     tables = tr.feature_store.tables if tr.feature_store is not None else {}
-    low = fns["epoch"].lower(tr.params, tr.opt_state, tr.stepno,
-                             tr._sparse_pack(), tables,
-                             tr.device_sampler.tables, fns["put"](xs))
+    return fns["epoch"].lower(tr.params, tr.opt_state, tr.stepno,
+                              tr._sparse_pack(), tables,
+                              tr.device_sampler.tables, fns["put"](xs))
+
+
+def _lowered_names(task, **hp):
+    low = _lowered_epoch(task, **hp)
     return NAME.findall(low.as_text(debug_info=True))
 
 
@@ -93,6 +98,21 @@ def test_backward_ops_carry_their_forward_scope():
     names = _lowered_names("node_classification")
     for s in ("gnn.layer0", "gnn.layer1", "encode", "head"):
         assert any(f"transpose(jvp({s}))" in n for n in names), s
+
+
+@pytest.mark.parametrize("lowering", ["single", "dp"])
+def test_spot_target_lowers_without_gather_or_sort(lowering):
+    """SpotTarget's membership test is compares and reductions only: a
+    gather or sort under its scope is the searched form coming back,
+    one scalar gather per query per search step."""
+    hlo = _lowered_epoch("link_prediction",
+                         **LOWERINGS[lowering]).as_text(dialect="hlo",
+                                                        debug_info=True)
+    ops = [m.group(1) for line in hlo.splitlines()
+           if (m := HLO_OP.search(line))
+           and "spot_target" in m.group(2).split("/")]
+    assert "compare" in ops and "reduce" in ops
+    assert not {"gather", "sort", "dynamic-slice"} & set(ops), ops
 
 
 def _first_epoch_losses(task, **hp):
