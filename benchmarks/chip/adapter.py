@@ -2,8 +2,9 @@
 trainer's weight tree, its embedding tables, its train loader and the
 hook around its epoch program.  Everything else under
 ``benchmarks/chip`` reads the program only through these functions, so
-a change of the program's layout (stacked per-relation weights, say)
-meets the benchmark here alone.
+a change of the program's layout meets the benchmark here, or, for the
+weights of one kind of GNN layer (stacked per-relation weights, say), in
+that layer's ``models/<gs.gnn.model>.py``.
 """
 from __future__ import annotations
 
@@ -16,9 +17,10 @@ from benchmarks.chip import reference, spec
 
 def weight_shapes(cell: spec.Cell, gd):
     """The dense weights' tree (shapes only), in the layout the program
-    keeps them (input encoders, RGCN layers, the task head), and the
-    ``(rows, dim)`` of each featureless node type's embedding table.
-    The reference computes over the same tree."""
+    keeps them (input encoders, the GNN layers of the configuration's
+    ``models/<gs.gnn.model>.py``, the task head), and the ``(rows, dim)``
+    of each featureless node type's embedding table.  The reference
+    computes over the same tree."""
     import jax
     import jax.numpy as jnp
     gs = cell.config["gs"]
@@ -29,9 +31,7 @@ def weight_shapes(cell: spec.Cell, gd):
     ekeys = sorted(reference.ekey(e) for e in gd.edges)
     z = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     ntypes = sorted(gd.num_nodes)
-    layer = {"w_rel": {e: z(H, H) for e in ekeys},
-             "w_self": {nt: z(H, H) for nt in ntypes},
-             "b": {nt: z(H) for nt in ntypes}}
+    layer = spec.gnn_model(cell).layer_shapes(ntypes, ekeys, H, gs["gnn"])
     if gs["task"] == "node_classification":
         C = gs["node_classification"]["num_classes"]
         dec = {"w1": z(H, H), "b1": z(H), "w2": z(H, C), "b2": z(C)}

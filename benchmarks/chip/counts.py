@@ -16,15 +16,19 @@ AdamW moments.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Callable, Dict, Tuple
 
 
 def step_counts(layers, input_dims: Dict[str, int], tables: Dict[str, int],
-                hidden: int, head: dict, dense_params: int) -> Dict[str, float]:
+                hidden: int, head: dict, dense_params: int,
+                layer_flops: Callable[..., Tuple[float, float]]
+                ) -> Dict[str, float]:
     """``layers``: ``reference.plan`` of one chip's batch; ``input_dims``:
     every input node type's row width; ``tables``: the node types whose
     rows are learnable embeddings (and their width); ``head``: ``{"kind":
-    "nc", "batch", "classes"}`` or ``{"kind": "lp", "batch", "k"}``."""
+    "nc", "batch", "classes"}`` or ``{"kind": "lp", "batch", "k"}``;
+    ``layer_flops(layer)``: one GNN layer's forward and backward FLOPs
+    (``models/<gs.gnn.model>.py``)."""
     H = hidden
     rows0 = dict(layers[0].src)
     fwd = bwd = 0.0
@@ -33,14 +37,9 @@ def step_counts(layers, input_dims: Dict[str, int], tables: Dict[str, int],
         fwd += mm
         bwd += mm * (2 if nt in tables else 1)
     for layer in layers:
-        for _, n in layer.dst:
-            fwd += 2.0 * n * H * H
-            bwd += 4.0 * n * H * H
-        for _, n, f, _ in layer.edges:
-            agg = 2.0 * n * f * H
-            mm = 2.0 * n * H * H
-            fwd += agg + mm
-            bwd += agg + 2 * mm
+        f, b = layer_flops(layer)
+        fwd += f
+        bwd += b
     B = head["batch"]
     if head["kind"] == "nc":
         mm = 2.0 * B * H * (H + head["classes"])

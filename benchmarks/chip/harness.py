@@ -154,7 +154,8 @@ def run_reference(cell: spec.Cell, gd, pseed: int, blocks, products=None,
     import jax.numpy as jnp
     fam = spec.graph_family(cell.config["graph"]["family"])
     ref = reference.Reference(cell.config, gd, int(cell.traffic["batch_size"]),
-                              products=products, storage=storage, fault=fault)
+                              spec.gnn_model(cell), products=products,
+                              storage=storage, fault=fault)
     params, tables = bench_weights(*adapter.weight_shapes(cell, gd), pseed,
                                    cell.config.get("weights", {}))
     feats = fam.device_features(gd)
@@ -210,7 +211,10 @@ def step_work(cell: spec.Cell, gd) -> Dict[str, float]:
     n_dense = sum(int(np.prod(x.shape)) for x in
                   jax.tree_util.tree_leaves(
                       adapter.weight_shapes(cell, gd)[0]))
-    return counts.step_counts(layers, dims, tables, H, head, n_dense)
+    gnn = spec.gnn_model(cell)
+    return counts.step_counts(
+        layers, dims, tables, H, head, n_dense,
+        lambda layer: gnn.layer_flops(layer, H, gs["gnn"]))
 
 
 def leaf_changes(state, init) -> Dict[str, float]:

@@ -1,4 +1,4 @@
-"""Plain RGCN training reference: the semantics the benchmark holds the
+"""Plain GNN training reference: the semantics the benchmark holds the
 program to, written from the description below in straightforward
 ``jax.numpy``.  It imports nothing of the program.
 
@@ -12,9 +12,9 @@ program to, written from the description below in straightforward
   replacement) and is masked when ``deg_i == 0``.
 - Frontier: per node type, the layer's own rows first, then each
   relation's draws, relations in sorted order.
-- RGCN layer: ``h'_v = b + h_v W_self + sum_r mean_{unmasked draws u of
-  r}(h_u) W_r``, ReLU between layers; the input encoder is ``ReLU(x W +
-  b)`` per node type, ``x`` a feature row or a learnable embedding row.
+- Input encoder: ``ReLU(x W + b)`` per node type, ``x`` a feature row or
+  a learnable embedding row.  Then one message-passing layer per fanout,
+  as the configuration's ``models/<gs.gnn.model>.py`` states it.
 - Node classification: a two-layer ReLU MLP, softmax cross-entropy over
   the unmasked seeds.
 - Link prediction: DistMult with the target relation's vector; ``k``
@@ -178,27 +178,15 @@ def matmul(products: str):
     return lambda a, b: jnp.matmul(rnd(a), rnd(b), precision=hi)
 
 
-def embed(params, layers, masks, x, mm):
-    """Seed-layer embeddings of the RGCN stack over one minibatch."""
+def embed(params, layers, masks, x, mm, gnn):
+    """Seed-layer embeddings over one minibatch: the input encoder, then
+    ``gnn.layer`` (``models/<gs.gnn.model>.py``) for each layer."""
     relu = jax.nn.relu
     h = {nt: relu(mm(v, params["gnn"]["input"][nt]["w"])
                   + params["gnn"]["input"][nt]["b"]) for nt, v in x.items()}
     for li, layer in enumerate(layers):
-        p = params["gnn"]["layers"][li]
-        out = {}
-        for nt, n in layer.dst:
-            acc = mm(h[nt][:n], p["w_self"][nt]) + p["b"][nt]
-            for et, nd, f, off in layer.edges:
-                if et[2] != nt:
-                    continue
-                rows = h[et[0]][off:off + nd * f].reshape(nd, f, -1)
-                w = masks[li][ekey(et)].astype(rows.dtype)
-                agg = (rows * w[..., None]).sum(1) / jnp.maximum(
-                    w.sum(1), 1)[:, None]
-                acc = acc + mm(agg, p["w_rel"][ekey(et)])
-            out[nt] = acc
-        h = out if li == len(layers) - 1 else {nt: relu(v)
-                                               for nt, v in out.items()}
+        h = gnn.layer(params["gnn"]["layers"][li], layer, masks[li], h, mm,
+                      li == len(layers) - 1)
     return h
 
 
@@ -264,10 +252,11 @@ def adagrad(table, gsum, summed, lr, eps):
 
 class Reference:
     """The first steps of one run of a configuration, from the same
-    weights, tables, features and seed blocks the program was given."""
+    weights, tables, features and seed blocks the program was given;
+    ``gnn`` is the configuration's ``models/<gs.gnn.model>.py``."""
 
-    def __init__(self, cfg: dict, graph, batch_size: int, products=None,
-                 storage=None, fault=None):
+    def __init__(self, cfg: dict, graph, batch_size: int, gnn,
+                 products=None, storage=None, fault=None):
         gs = cfg["gs"]
         self.task = gs["task"]
         self.opt = dict(cfg["reference"]["optimizer"],
@@ -279,6 +268,7 @@ class Reference:
         if fault not in FAULTS:
             raise ValueError(f"fault {fault!r} is not one of {FAULTS}")
         self.fault = fault
+        self.gnn = gnn
         self.B = int(batch_size)
         etypes = sorted(graph.edges)
         fan = gs["gnn"]["fanout"]
@@ -335,7 +325,8 @@ class Reference:
             keep = (jnp.arange(self.B) < self.B // 2).astype(jnp.float32)
 
         def loss_of(p, rows):
-            h = embed(p, self.layers, masks, {**x_feat, **rows}, self.mm)
+            h = embed(p, self.layers, masks, {**x_feat, **rows}, self.mm,
+                      self.gnn)
             if self.task == "node_classification":
                 mask = blocks["seed_mask"]
                 if keep is not None:

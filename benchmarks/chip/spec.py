@@ -3,9 +3,11 @@
 ``BENCHMARK.json`` lists the cells (``workloads``), the configurations
 and the metrics.  Each configuration is ``configs/<name>.json``, each
 traffic mix ``traffic/<name>.json``, each per-layer metric
-``metrics/<name>.py`` and each cell's correctness limits
-``limits/<cell>.json``, all beside this file.  A new cell is new files
-plus new entries in ``BENCHMARK.json``; nothing here changes.
+``metrics/<name>.py``, each cell's correctness limits
+``limits/<cell>.json``, and each configuration's GNN layer
+``models/<gs.gnn.model>.py``, all beside this file.  A new cell, or a new
+GNN architecture, is new files plus new entries in ``BENCHMARK.json``;
+nothing here changes.
 """
 from __future__ import annotations
 
@@ -63,6 +65,8 @@ class Cell:
     limits: dict
     end_to_end: List[Metric]
     per_layer: List[Metric]
+    # the directory the cell's files were read from
+    here: Path
 
 
 def _metric(entry: dict) -> Metric:
@@ -104,7 +108,7 @@ def find_cell(name: str, bench: Optional[dict] = None,
         traffic=_json(here / "traffic" / f"{w['traffic']}.json"),
         limits=_json(here / "limits" / f"{name}.json"),
         end_to_end=[m for m in e2e if m.applies_to(name)],
-        per_layer=[m for m in per if m.applies_to(name)])
+        per_layer=[m for m in per if m.applies_to(name)], here=here)
 
 
 def _load(path: Path, modname: str):
@@ -128,6 +132,13 @@ def graph_family(name: str, here: Path = HERE):
     """The generator module ``graphs/<name>.py``."""
     return _load(here / "graphs" / f"{check_name(name, 'graph family')}.py",
                  "chip_graph_" + name)
+
+
+def gnn_model(cell: Cell):
+    """The module ``models/<gs.gnn.model>.py`` of the cell's configuration:
+    its GNN layer's ``layer_shapes``, ``layer`` and ``layer_flops``."""
+    name = check_name(cell.config["gs"]["gnn"]["model"], "GNN model")
+    return _load(cell.here / "models" / f"{name}.py", "chip_model_" + name)
 
 
 def peaks(device_kind: str, here: Path = HERE) -> Dict[str, float]:

@@ -10,9 +10,12 @@ def test_tiny_rgcn_counts_by_hand():
     # b has features (width 5); hidden 2; 3 classes
     layers = reference.plan([("a", "r", "b")], [3], {"b": 2})
     assert layers[0].src == (("a", 6), ("b", 2))
+    rgcn = spec.gnn_model(spec.find_cell("mag-nc.train"))
     c = counts.step_counts(layers, {"a": 4, "b": 5}, {"a": 4}, hidden=2,
                            head={"kind": "nc", "batch": 2, "classes": 3},
-                           dense_params=10)
+                           dense_params=10,
+                           layer_flops=lambda lay: rgcn.layer_flops(lay, 2,
+                                                                    {}))
     enc_a, enc_b = 2 * 6 * 4 * 2, 2 * 2 * 5 * 2        # 96, 40
     self_mm = 2 * 2 * 2 * 2                             # 16
     agg, rel_mm = 2 * 2 * 3 * 2, 2 * 2 * 2 * 2          # 24, 16

@@ -8,6 +8,7 @@ import subprocess
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from benchmarks.chip import reference, spec
@@ -117,9 +118,101 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
         {"steps": 7}) == 7
 
 
+TOY_GNN = '''"""A sum over each relation's unmasked draws, one weight per
+relation and no self weight."""
+import jax
+import jax.numpy as jnp
+
+from benchmarks.chip.reference import ekey
+
+
+def layer_shapes(ntypes, ekeys, hidden, gnn):
+    return {"w": {e: jax.ShapeDtypeStruct((hidden, hidden), jnp.float32)
+                  for e in ekeys}}
+
+
+def layer(p, plan_layer, masks, h, mm, last):
+    out = {}
+    for et, nd, f, off in plan_layer.edges:
+        rows = h[et[0]][off:off + nd * f].reshape(nd, f, -1)
+        agg = (rows * masks[ekey(et)][..., None]).sum(1)
+        out[et[2]] = out.get(et[2], 0.0) + mm(agg, p["w"][ekey(et)])
+    return out
+
+
+def layer_flops(plan_layer, hidden, gnn):
+    fwd = bwd = 0.0
+    for _, n, f, _ in plan_layer.edges:
+        agg, mm = 2.0 * n * f * hidden, 2.0 * n * hidden * hidden
+        fwd += agg + mm
+        bwd += agg + 2 * mm
+    return fwd, bwd
+'''
+
+
+def test_a_new_gnn_needs_only_new_files(tmp_path):
+    """A configuration whose ``gs.gnn.model`` names a new file under
+    ``models/`` is weighted, referenced and counted with no change to a
+    file already there."""
+    from bench_small import graph_of_counts, reference_run, small_cell
+
+    from benchmarks.chip import adapter, harness
+    here = spec.HERE
+    for sub in ("configs", "traffic", "limits", "metrics", "graphs",
+                "models"):
+        shutil.copytree(here / sub, tmp_path / sub)
+    (tmp_path / "models" / "toysum.py").write_text(TOY_GNN)
+    cfg = json.loads((here / "configs" / "rgcn-mag-nc.json").read_text())
+    cfg["name"] = "toysum-mag-nc"
+    cfg["gs"]["gnn"]["model"] = "toysum"
+    (tmp_path / "configs" / "toysum-mag-nc.json").write_text(json.dumps(cfg))
+    (tmp_path / "limits" / "mag-nc.toysum.json").write_text(
+        (here / "limits" / "mag-nc.train.json").read_text())
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": "toysum-mag-nc", "source": "s",
+                             "file": "x", "reduced": [], "why": "w"})
+    bench["workloads"].append({"name": "mag-nc.toysum",
+                               "config": "toysum-mag-nc",
+                               "traffic": "train.e64", "chips": 1,
+                               "why": "w"})
+    cell = small_cell("mag-nc.toysum", bench=bench, here=tmp_path)
+    rgcn = small_cell("mag-nc.train")
+    gd = graph_of_counts(cell)
+    ekeys = sorted(reference.ekey(e) for e in gd.edges)
+    H = cell.config["gs"]["gnn"]["hidden"]
+
+    dense, tables = adapter.weight_shapes(cell, gd)
+    assert [set(lay) for lay in dense["gnn"]["layers"]] == [{"w"}, {"w"}]
+    assert sorted(dense["gnn"]["layers"][0]["w"]) == ekeys
+    assert {tuple(x.shape) for x in dense["gnn"]["layers"][1]["w"].values()} \
+        == {(H, H)}
+    assert tables == adapter.weight_shapes(rgcn, gd)[1]
+
+    out = reference_run(cell)
+    assert np.isfinite(out["losses"][0])
+    g = out["grad_norms"]["params"]["gnn"]["layers"][0]["w"]
+    assert sorted(g) == ekeys and all(np.isfinite(list(g.values())))
+
+    # the toy is RGCN without its self products (6 n H^2 a destination
+    # row, forward and backward) and the self weights and biases
+    seeds = {cell.config["gs"]["node_classification"]["target_ntype"]:
+             int(cell.traffic["batch_size"])}
+    layers = reference.plan(sorted(gd.edges), cell.config["gs"]["gnn"]
+                            ["fanout"], seeds)
+    toy, full = harness.step_work(cell, gd), harness.step_work(rgcn, gd)
+    assert full["flops"] - toy["flops"] == sum(
+        6.0 * n * H * H for lay in layers for _, n in lay.dst)
+    per_layer = len(gd.num_nodes) * (H * H + H)
+    assert full["bytes"] - toy["bytes"] == 7 * 4.0 * 2 * per_layer
+
+
 def test_reference_imports_nothing_of_the_program():
-    src = Path(reference.__file__).read_text()
-    assert "repro" not in src
+    """The reference and every GNN layer it runs."""
+    files = [Path(reference.__file__)] + sorted(
+        (spec.HERE / "models").glob("*.py"))
+    assert len(files) > 1
+    for f in files:
+        assert "repro" not in f.read_text(), f
 
 
 def test_run_without_a_tpu_fails_and_prints_no_result():
