@@ -18,6 +18,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.gnn.aggregate import masked_mean, masked_softmax, masked_sum
 from repro.gnn.schema import LayerSchema
@@ -113,7 +114,7 @@ def sage_apply(params, lsch: LayerSchema, arrays_l, src_h):
 def gat_init(rng, ntypes, etypes, d_in, d_out, nheads=4):
     dh = d_out // nheads
     ks = _keys(rng, 3 * len(etypes))
-    p = {"w": {}, "a_src": {}, "a_dst": {}, "nheads": nheads}
+    p = {"w": {}, "a_src": {}, "a_dst": {}}
     for i, (ek, st, dt) in enumerate(etypes):
         p["w"][ek] = _glorot(ks[3 * i], (d_in[st], d_out))
         p["a_src"][ek] = _glorot(ks[3 * i + 1], (nheads, dh))
@@ -122,7 +123,7 @@ def gat_init(rng, ntypes, etypes, d_in, d_out, nheads=4):
 
 
 def _gat_edge(params, em, arrays_l, src_h, lsch, extra_nbr=None):
-    nheads = params["nheads"]
+    nheads = params["a_src"][em.ekey].shape[0]
     w = params["w"][em.ekey]
     dh = w.shape[1] // nheads
     nbr = _nbr_rows(src_h, em)
@@ -196,57 +197,106 @@ def rgat_apply(params, lsch: LayerSchema, arrays_l, src_h):
 
 
 # ---------------------------------------------------------------------------
-# HGT  [9]  (typed Q/K/V projections + per-relation message/attention mats)
+# HGT  [9]  (Hu et al. WWW 2020, arXiv:2003.01332, §3 eqs. 1-5)
 # ---------------------------------------------------------------------------
 def hgt_init(rng, ntypes, etypes, d_in, d_out, nheads=4):
+    """Per node type: ``k``, ``q``, ``v``, ``a`` (d x d), the gate
+    ``skip`` (shape (1,)) and LayerNorm ``norm`` ``scale``/``bias``; per
+    relation: ``w_att``, ``w_msg`` (H, dh, dh) and ``prior`` (H,).  The
+    head count is ``prior.shape[0]``."""
     dh = d_out // nheads
-    nk = 4 * len(ntypes) + 2 * len(etypes)
-    ks = _keys(rng, nk)
-    i = iter(ks)
-    p = {"nheads": nheads,
-         "k_proj": {}, "q_proj": {}, "v_proj": {},
-         "w_att": {}, "w_msg": {}, "prior": {}, "skip": {}}
+    i = iter(_keys(rng, 4 * len(ntypes) + 2 * len(etypes)))
+    p = {name: {} for name in ("k", "q", "v", "a", "skip", "norm",
+                               "w_att", "w_msg", "prior")}
     for nt in ntypes:
-        p["k_proj"][nt] = _glorot(next(i), (d_in[nt], d_out))
-        p["q_proj"][nt] = _glorot(next(i), (d_in[nt], d_out))
-        p["v_proj"][nt] = _glorot(next(i), (d_in[nt], d_out))
-    for ek, st, dt in etypes:
-        p["w_att"][ek] = jnp.stack([jnp.eye(dh)] * nheads)
-        p["w_msg"][ek] = jnp.stack([jnp.eye(dh)] * nheads)
+        for name in ("k", "q", "v"):
+            p[name][nt] = _glorot(next(i), (d_in[nt], d_out))
+        p["a"][nt] = _glorot(next(i), (d_out, d_out))
+        p["skip"][nt] = jnp.ones((1,), jnp.float32)
+        p["norm"][nt] = {"scale": jnp.ones((d_out,), jnp.float32),
+                         "bias": jnp.zeros((d_out,), jnp.float32)}
+    for ek, _, _ in etypes:
+        for name in ("w_att", "w_msg"):
+            p[name][ek] = jax.random.normal(
+                next(i), (nheads, dh, dh), jnp.float32) * dh ** -0.5
         p["prior"][ek] = jnp.ones((nheads,), jnp.float32)
-    # typed skip projection
-    p["skip"] = {nt: _glorot(next(i), (d_in[nt], d_out)) for nt in ntypes}
     return p
 
 
-def hgt_apply(params, lsch: LayerSchema, arrays_l, src_h):
-    H = params["nheads"]
-    out = {}
+def _joint_softmax(scores, masks):
+    """Attention over every unmasked slot of a destination across all of
+    its relations: ``scores`` (n, f_r, H) and ``masks`` (n, f_r), one of
+    each per relation into one destination type, give each relation's
+    weights (n, f_r, H).  Masked slots, and every slot of a destination
+    with none unmasked, weigh 0."""
+    att = masked_softmax(jnp.concatenate(scores, axis=1),
+                         jnp.concatenate(masks, axis=1)[..., None])
+    ends = np.cumsum([x.shape[1] for x in scores])
+    return [att[:, e - x.shape[1]:e] for e, x in zip(ends, scores)]
+
+
+def hgt_attention(params, lsch: LayerSchema, arrays_l, src_h):
+    """Each relation's attention weights ``{ekey: (n, f, H)}`` and each
+    destination type's message ``{ntype: (n, d)}`` (eqs. 1-4, before
+    the GELU)."""
+    H = next(iter(params["prior"].values())).shape[0]
+    by_dst = {}
     for em in lsch.edges:
-        w = params["k_proj"][em.src_t]
-        d_out = w.shape[1]
-        dh = d_out // H
-        nbr = _nbr_rows(src_h, em)
-        mask = arrays_l["masks"][em.ekey]
-        k = (nbr @ w).reshape(em.num_dst, em.fanout, H, dh)
-        v = (nbr @ params["v_proj"][em.src_t]).reshape(
-            em.num_dst, em.fanout, H, dh)
-        q = (_self_rows(src_h, lsch, em.dst_t)
-             @ params["q_proj"][em.dst_t]).reshape(em.num_dst, H, dh)
-        k = jnp.einsum("nfhd,hde->nfhe", k, params["w_att"][em.ekey])
-        v = jnp.einsum("nfhd,hde->nfhe", v, params["w_msg"][em.ekey])
-        sc = jnp.einsum("nfhd,nhd->nfh", k, q) * (dh ** -0.5)
-        sc = sc * params["prior"][em.ekey][None, None, :]
-        att = masked_softmax(sc.transpose(0, 2, 1).reshape(-1, em.fanout),
-                             jnp.repeat(mask, H, axis=0))
-        att = att.reshape(em.num_dst, H, em.fanout).transpose(0, 2, 1)
-        msg = jnp.einsum("nfh,nfhd->nhd", att, v).reshape(em.num_dst, -1)
-        out[em.dst_t] = out.get(em.dst_t, 0.0) + msg
-    res = {}
+        by_dst.setdefault(em.dst_t, []).append(em)
+    att, msg = {}, {}
+    for nt, ems in by_dst.items():
+        n = lsch.dst_count(nt)
+        q = (_self_rows(src_h, lsch, nt) @ params["q"][nt]).reshape(n, H, -1)
+        dh = q.shape[-1]
+        scores, values = [], []
+        for em in ems:
+            nbr = _nbr_rows(src_h, em)
+            k = (nbr @ params["k"][em.src_t]).reshape(n, em.fanout, H, dh)
+            v = (nbr @ params["v"][em.src_t]).reshape(n, em.fanout, H, dh)
+            k = jnp.einsum("nfhd,hde->nfhe", k, params["w_att"][em.ekey])
+            scores.append(jnp.einsum("nfhe,nhe->nfh", k, q)
+                          * params["prior"][em.ekey] * dh ** -0.5)
+            values.append(jnp.einsum("nfhd,hde->nfhe", v,
+                                     params["w_msg"][em.ekey]))
+        alphas = _joint_softmax(
+            scores, [arrays_l["masks"][em.ekey] for em in ems])
+        m = sum(jnp.einsum("nfh,nfhe->nhe", a, v)
+                for a, v in zip(alphas, values))
+        msg[nt] = m.reshape(n, H * dh)
+        att.update({em.ekey: a for em, a in zip(ems, alphas)})
+    return att, msg
+
+
+def _layer_norm(x, p, eps=1e-5):
+    mu = x.mean(-1, keepdims=True)
+    var = jnp.square(x - mu).mean(-1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+
+
+def hgt_apply(params, lsch: LayerSchema, arrays_l, src_h):
+    """The published layer:
+
+        out_t = LayerNorm_t(s * (GELU(m_t) A_t) + (1 - s) * h_t),
+        s = sigmoid(skip_t),
+
+    with ``m_t`` from ``hgt_attention``: per head ``i``, the score of a
+    slot (s, r) is ``(k_s,i W^att_r,i . q_t,i) * prior_r,i / sqrt(dh)``
+    and one softmax runs over all unmasked slots of ``t`` across every
+    relation; ``m_t`` concatenates the heads' sums of ``alpha * (v_s,i
+    W^msg_r,i)``.  A destination with no unmasked slot gets ``m_t = 0``.
+    Departures: no relative temporal encoding, no dropout, no biases in
+    the K/Q/V/A projections; the shared input encoder ReLU(x W + b)
+    stands in for pyHGT's tanh adapter."""
+    _, msg = hgt_attention(params, lsch, arrays_l, src_h)
+    out = {}
     for nt in dict(lsch.dst_counts):
-        skip = _self_rows(src_h, lsch, nt) @ params["skip"][nt]
-        res[nt] = jax.nn.gelu(out.get(nt, 0.0)) + skip
-    return res
+        h = _self_rows(src_h, lsch, nt)
+        gate = jax.nn.sigmoid(params["skip"][nt])
+        upd = (jax.nn.gelu(msg[nt], approximate=False) @ params["a"][nt]
+               if nt in msg else 0.0)
+        out[nt] = _layer_norm(gate * upd + (1 - gate) * h,
+                              params["norm"][nt])
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +328,10 @@ def tgat_apply(params, lsch: LayerSchema, arrays_l, src_h):
         out[em.dst_t] = out.get(em.dst_t, 0.0) + msg
     return out
 
+
+# layers that end in their own normalization, so no ReLU follows them
+# between layers (HGT's LayerNorm, eq. 5)
+NORMALIZED = frozenset({"hgt"})
 
 LAYERS = {
     "gcn": (gcn_init, gcn_apply),

@@ -13,7 +13,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.gnn.layers import LAYERS
+from repro.gnn.layers import LAYERS, NORMALIZED
 from repro.gnn.schema import BlockSchema
 
 GNN_ZOO = tuple(LAYERS)
@@ -78,7 +78,8 @@ def gnn_apply_blocks(params, model: GSgnnModel, schema: BlockSchema,
                 arrays_l["delta_t"] = arrays["delta_t"][l]
             with tracing.scope(f"gnn.layer{l}"):
                 h = apply_fn(params["layers"][l], lsch, arrays_l, h)
-                if l < schema.num_layers - 1:
+                if (l < schema.num_layers - 1
+                        and model.kind not in NORMALIZED):
                     h = {nt: jax.nn.relu(v) for nt, v in h.items()}
     return h
 
